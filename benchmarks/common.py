@@ -58,6 +58,7 @@ print("STATUS", rec["status"])
 """
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake-device dry run; never claims a chip
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=3000)
     if proc.returncode != 0:

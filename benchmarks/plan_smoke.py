@@ -6,7 +6,7 @@ the PR 1 (search-disabled) planner; per *optimizer* cell, the whole-plan pass
 pipeline's pre- vs post-pass modeled wire bytes, collective-launch counts,
 fused-bucket counts, and plan-build wall time; per *inline* cell
 (whole-program passes), the pre- vs post-pass whole-program wire bytes and
-launches (inner pjit/scan bodies priced at trip count), inlined-body /
+launches (inner jit/scan bodies priced at trip count), inlined-body /
 hoisted-reshard / in-body-reshard counts, and the overlap scheduler's modeled
 makespan-to-serial ratio; per *autoshard* cell, the searched annotation-free
 assignment's modeled cost vs the hand-annotated Table-1 baseline under a
@@ -225,13 +225,13 @@ def _opt_cells():
 
 
 # ---------------------------------------------------------------------------------
-# whole-program cells (PR 4): pjit inlining, scan hoisting, overlap scheduling
+# whole-program cells (PR 4): jit inlining, scan hoisting, overlap scheduling
 # ---------------------------------------------------------------------------------
 
 
 def _inline_programs():
     """Benchmark programs whose wins need the whole-program passes: a shared
-    in-body param gather (CSE only fires after pjit inlining), in-body psums
+    in-body param gather (CSE only fires after jit inlining), in-body psums
     (fusable only after inlining), a loop-invariant scan gather (hoist), and
     an independent gather behind a compute chain (overlap scheduling)."""
     import jax
@@ -254,7 +254,7 @@ def _inline_programs():
     gather_blk = jax.jit(gather_block)
 
     def pjit_shared_param_gather(x, w):
-        # two pjit bodies each gathering the same param: the duplicate
+        # two jit bodies each gathering the same param: the duplicate
         # collective is invisible to CSE until inlining dissolves the calls
         return gather_blk(x, w) + gather_blk(jnp.sin(x), w)
 
@@ -298,7 +298,7 @@ def _inline_programs():
 
 
 def _inner_reshards(plan) -> int:
-    """Reshard steps still living inside pjit/scan bodies (recursive)."""
+    """Reshard steps still living inside jit/scan bodies (recursive)."""
     n = 0
     for s in plan.steps:
         if s.inner is not None:
@@ -473,7 +473,7 @@ _AUTOSHARD_CASES = (
 
 
 def _autoshard_mlp_problem(mesh):
-    """A scan/pjit-free search problem (plain MLP): its plan has no inner
+    """A scan/jit-free search problem (plain MLP): its plan has no inner
     bodies, so the whole-program passes leave its PlanCost components (wire
     bytes, launches, per-device FLOPs) untouched — this cell's score moves
     *only* with the scoring objective, isolating the max-of-terms swap from
@@ -549,7 +549,7 @@ def _autoshard_cells():
             f"autoshard_{arch.replace('.', '_').replace('-', '_')}",
             arch, mesh, budget, solve_registry,
         ))
-    # scan/pjit-free cell: score isolates the objective formula (see
+    # scan/jit-free cell: score isolates the objective formula (see
     # _autoshard_mlp_problem); budget sits between the hand-annotated and
     # replicated peaks so the search must do real work, like the golden tests
     closed, baseline = _autoshard_mlp_problem(mesh)

@@ -196,25 +196,24 @@ print("CPERM", txt.count("collective-permute"))
 """
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # 8 fake CPU devices; never claims a chip
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=1200)
-    rows = []
-    if proc.returncode == 0:
-        parity = [l for l in proc.stdout.splitlines() if l.startswith("PARITY")]
-        cperm = [l for l in proc.stdout.splitlines() if l.startswith("CPERM")]
-        rows.append((
-            "table8/unet3d_spatial8", 0.0,
-            f"parity_err={float(parity[0].split()[1]):.2e} "
-            f"halo_collective_permutes={cperm[0].split()[1]}",
-        ))
-    else:
-        rows.append(("table8/unet3d_spatial8", 0.0,
-                     f"FAILED: {proc.stderr[-200:]}"))
-    return rows
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"table8 spatial child failed:\n{proc.stderr[-1500:]}")
+    parity = [l for l in proc.stdout.splitlines() if l.startswith("PARITY")]
+    cperm = [l for l in proc.stdout.splitlines() if l.startswith("CPERM")]
+    return [(
+        "table8/unet3d_spatial8", 0.0,
+        f"parity_err={float(parity[0].split()[1]):.2e} "
+        f"halo_collective_permutes={cperm[0].split()[1]}",
+    )]
 
 
 # --- kernels microbench (not a paper table; supports §Perf) -------------------------
 def kernels_micro():
+    import jax
     import jax.numpy as jnp
     from repro.kernels.ops import attention
     from repro.kernels.ref import attention_ref
@@ -224,8 +223,10 @@ def kernels_micro():
     k = jnp.asarray(rng.standard_normal((1, 4, 256, 64)), jnp.float32)
     us_k = time_call(lambda: attention(q, k, k, causal=True).block_until_ready())
     us_r = time_call(lambda: attention_ref(q, k, k, causal=True).block_until_ready())
+    backend = jax.default_backend()
+    mode = "interpret mode" if backend == "cpu" else "compiled"
     return [
-        ("kernels/flash_attention_interpret", us_k, "pallas interpret mode (CPU)"),
+        ("kernels/flash_attention", us_k, f"pallas {mode} ({backend})"),
         ("kernels/attention_ref", us_r, "pure-jnp oracle"),
     ]
 

@@ -2,7 +2,7 @@
 
 Counts matmul/conv FLOPs exactly and elementwise ops at 1 flop/element,
 multiplying ``scan`` bodies by their trip count (the correction XLA's
-``cost_analysis()`` lacks) and recursing into pjit/remat/custom_* calls.
+``cost_analysis()`` lacks) and recursing into jit/remat/custom_* calls.
 Used in tests to validate the layers-delta roofline accounting.
 """
 from __future__ import annotations

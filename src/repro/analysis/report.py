@@ -178,7 +178,7 @@ def plan_opt_table(path: str) -> str:
             )
     lines.append("")
     lines.append(
-        "Passes (in order): pjit inlining, scan-invariant hoisting, reshard "
+        "Passes (in order): jit inlining, scan-invariant hoisting, reshard "
         "CSE, dead-reshard elimination, output-alias sinking, collective "
         "fusion/bucketing (roofline-capped), overlap-aware scheduling "
         "(max-of-terms roofline) — see `core/plan_opt.py`."

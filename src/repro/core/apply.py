@@ -57,7 +57,7 @@ def eval_with_constraints(jaxpr: excore.Jaxpr, consts, prop: Propagation, jmesh,
             outvals = [_wsc(invals[0], eqn.params["sharding"], jmesh)]
         elif prim.name == "scan":
             outvals = _eval_scan(eqn, invals, prop, jmesh)
-        elif prim.name == "pjit":
+        elif prim.name == "jit":
             inner = prop.sub.get(id(eqn))
             sub = eqn.params["jaxpr"]
             if inner is None:

@@ -1,11 +1,9 @@
 """Version bridges for the jax APIs the partitioner depends on — and the
 repo-wide numeric tolerance policy.
 
-The partitioner executes local programs under ``shard_map``; the surface for
-that function has moved twice (``jax.experimental.shard_map.shard_map`` with
-``check_rep`` -> ``jax.shard_map`` with ``check_vma``).  Everything in
-``repro.core`` goes through this module so the rest of the code can assume one
-stable spelling.
+The partitioner executes local programs under ``shard_map``; everything in
+``repro.core`` reaches it and the mesh APIs through this module, so the rest of
+the code has one spelling of each.
 
 **Tolerance policy** (:data:`TOLERANCES`, :func:`assert_close`): partitioned
 programs are *mathematically* identical to their single-device references but
@@ -72,83 +70,41 @@ def assert_close(got, want, kind: str = "f32", **kwargs):
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` with replication checking disabled by default.
+    """``jax.shard_map`` with replication checking disabled by default.
 
     The reference partitioner inserts its own collectives, which the
-    replication checker cannot see through; both jax spellings accept a flag to
-    turn it off but disagree on its name.
+    replication checker cannot see through.
     """
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
 def cost_analysis_dict(compiled):
-    """``compiled.cost_analysis()`` as a flat dict.
-
-    Older jax returns a one-element list of per-partition dicts; newer jax
-    returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()`` as a flat dict (``{}`` when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def get_abstract_mesh():
-    """The ambient mesh (or None): ``jax.sharding.get_abstract_mesh`` where it
-    exists, else the legacy thread-resources physical mesh.  Both expose
-    ``empty`` / ``axis_names`` / ``axis_sizes``."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as mesh_lib  # legacy resource env
-
-    return mesh_lib.thread_resources.env.physical_mesh
+    """The ambient mesh; exposes ``empty`` / ``axis_names`` / ``axis_sizes``."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(jmesh):
-    """Context manager making ``jmesh`` the ambient mesh.
-
-    ``jax.set_mesh`` where available; on older jax, ``jax.sharding.Mesh`` is
-    itself a context manager with the same effect.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(jmesh)
-    return jmesh
+    """Context manager making ``jmesh`` the ambient mesh."""
+    return jax.set_mesh(jmesh)
 
 
 def axis_size(name: str) -> int:
     """Static size of a named mesh axis inside a shard_map region."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)  # constant-folded to the axis size
+    return jax.lax.axis_size(name)
 
 
 def make_jax_mesh(shape, axis_names):
-    """A ``jax.sharding.Mesh`` with Auto axis types where supported."""
-    import inspect
-
-    shape, axis_names = tuple(shape), tuple(axis_names)
-    if not hasattr(jax, "make_mesh"):  # pragma: no cover - very old jax
-        from jax.experimental import mesh_utils
-
-        return jax.sharding.Mesh(
-            mesh_utils.create_device_mesh(shape), axis_names
-        )
-    kwargs = {}
-    try:
-        if "axis_types" in inspect.signature(jax.make_mesh).parameters and hasattr(
-            jax.sharding, "AxisType"
-        ):
-            kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    except (TypeError, ValueError):  # pragma: no cover
-        pass
-    return jax.make_mesh(shape, axis_names, **kwargs)
+    """A ``jax.sharding.Mesh`` with Auto axis types."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(shape), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import threading
+import time
 from typing import Dict, List, Optional
 
 import jax
@@ -125,8 +126,8 @@ class SpmdPartitioner:
         if name == "conv_general_dilated":
             self._conv(eqn)
             return
-        if name == "pjit":
-            self._pjit(eqn)
+        if name == "jit":
+            self._jit(eqn)
             return
         if name == "scan":
             self._scan(eqn)
@@ -317,7 +318,7 @@ class SpmdPartitioner:
         osh = Sharding(self.mesh, tuple(dm))
         self.write(eqn.outvars[0], out, osh)
 
-    def _pjit(self, eqn):
+    def _jit(self, eqn):
         sub = eqn.params["jaxpr"]
         inner_prop = self.prop.sub.get(id(eqn)) or Propagation(sub.jaxpr, self.mesh)
         inner = SpmdPartitioner(inner_prop, self.mesh)
@@ -471,6 +472,7 @@ class PlanCacheStats:
 class _CacheEntry:
     call: object  # jitted shard_map over the compiled plan
     plan: object  # PartitionPlan (for stats/reporting)
+    build_s: float = 0.0  # trace + propagate + plan lowering (no XLA compile)
 
 
 def _aval_key(a):
@@ -551,7 +553,7 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
     (``SpmdPartitioner``), which re-decides everything per trace — kept for
     differential testing and benchmarking against the compiled path.
     ``optimize=False`` skips the whole-program optimizer passes
-    (``plan_opt``: pjit inlining, scan-invariant reshard hoisting, reshard
+    (``plan_opt``: jit inlining, scan-invariant reshard hoisting, reshard
     CSE, dead-reshard elimination, collective fusion, overlap-aware
     scheduling) on the compiled plan.  ``process_cache=False`` opts this runner out of the
     process-level plan cache (shared across ``spmd_partition`` call sites,
@@ -620,6 +622,7 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         # resolved per build so $REPRO_MACHINE_PROFILE edits are picked up;
         # the digest keys the process cache (None = default constants)
         prof = resolve_profile(profile)
+        t0 = time.perf_counter()
         closed = jax.make_jaxpr(fn)(*args)
         pkey: Optional[tuple] = None
         if process_cache:
@@ -705,7 +708,7 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         # (the whole point — see the tracing contract in repro.obs.trace)
         traced_eager = tracer is not None and tracer.config.measured
         entry = _CacheEntry(shmapped if traced_eager else jax.jit(shmapped),
-                            plan)
+                            plan, time.perf_counter() - t0)
         if pkey is not None:
             _PROCESS_CACHE[pkey] = entry
         return entry

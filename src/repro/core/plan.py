@@ -25,10 +25,10 @@ consumers without touching closures.  Values produced mid-plan (a resharded
 operand, a pre-psum partial sum) live under :class:`ProxyVar` keys — plan-local
 SSA names that never collide with jaxpr vars.
 
-Inner ``pjit``/``scan`` bodies lower to their own plans, but not opaquely:
+Inner ``jit``/``scan`` bodies lower to their own plans, but not opaquely:
 the call step exposes the inner plan (``PlanStep.inner``) and its static call
 metadata (``PlanStep.call``), so the whole-program passes can splice trivial
-pjit bodies into the outer step list, hoist loop-invariant reshards out of
+jit bodies into the outer step list, hoist loop-invariant reshards out of
 scan bodies, and price inner collectives at trip count.
 
 Executing a plan is a straight walk of the step list with a dict environment;
@@ -136,13 +136,13 @@ class PlanStep:
     # -- cost-model annotations (consumed by lower_for_cost / PlanCost) -----
     flops: float = 0.0  # per-device local FLOPs of this step
     wbytes: Tuple[float, ...] = ()  # local bytes of each write (memory model)
-    transient_bytes: float = 0.0  # inner-plan live peak (scan/pjit steps)
-    # -- call steps (op == "pjit" / "scan") ---------------------------------
+    transient_bytes: float = 0.0  # inner-plan live peak (scan/jit steps)
+    # -- call steps (op == "jit" / "scan") ---------------------------------
     # The inner plan is exposed structurally (not just captured by the run
-    # closure) so whole-program passes can inline trivial pjit bodies, hoist
+    # closure) so whole-program passes can inline trivial jit bodies, hoist
     # loop-invariant reshards out of scan bodies, and price inner collectives
     # at trip count.  ``call`` carries the static call metadata the passes
-    # need: {"trips": int} for pjit (always 1), plus
+    # need: {"trips": int} for jit (always 1), plus
     # {"num_consts", "num_carry"} for scan.
     inner: Optional["PartitionPlan"] = None
     call: Dict = dataclasses.field(default_factory=dict)
@@ -231,6 +231,9 @@ class PlanStats:
     # (searches run / node-budget exhaustions / depth-cap prunes); filled by
     # compile_plan from collective_planner.search_telemetry()
     lattice: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # primitive name -> equations lowered by the gather-op-reshard fallback
+    # (inner jit/scan bodies included: they share their caller's stats)
+    fallbacks: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def count(self, kind: str, n: int = 1) -> None:
         self.collectives[kind] = self.collectives.get(kind, 0) + n
@@ -265,6 +268,7 @@ class PlanStats:
             "eqns": self.eqns,
             "steps": self.steps,
             "lattice": dict(self.lattice),
+            "fallbacks": dict(self.fallbacks),
         }
 
 
@@ -912,8 +916,8 @@ class PlanBuilder:
             self._reshape(eqn)
         elif name == "conv_general_dilated":
             self._conv(eqn)
-        elif name == "pjit":
-            self._pjit(idx, eqn)
+        elif name == "jit":
+            self._jit(idx, eqn)
         elif name == "scan":
             self._scan(idx, eqn)
         elif name == "stage_shift":
@@ -1367,7 +1371,7 @@ class PlanBuilder:
 
         return optimize_plan(plan)
 
-    def _pjit(self, idx: int, eqn) -> None:
+    def _jit(self, idx: int, eqn) -> None:
         sub = eqn.params["jaxpr"]
         inner_res = self._inner_result(idx, sub)
         # seed inner input shardings from ours where propagation left them open
@@ -1396,7 +1400,7 @@ class PlanBuilder:
                 _write(env, w, o)
 
         self.emit(PlanStep(
-            "compute", tuple(keys), outvars, run, op="pjit",
+            "compute", tuple(keys), outvars, run, op="jit",
             flops=inner_plan.total_flops(),
             transient_bytes=inner_plan.peak_bytes,
             inner=inner_plan, call={"trips": 1},
@@ -1509,6 +1513,7 @@ class PlanBuilder:
         in_shardings = [self.sharding_of(v) for v in eqn.invars]
         keep = fallback_keep_sharding(eqn, in_shardings, self.mesh)
         prim = eqn.primitive
+        self.stats.fallbacks[prim.name] = self.stats.fallbacks.get(prim.name, 0) + 1
         invars, outvars = list(eqn.invars), list(eqn.outvars)
         if keep is not None:
             kept_sh, params = keep
@@ -1629,7 +1634,7 @@ def compile_plan(
     """Lower a propagated (closed) jaxpr into an executable PartitionPlan.
 
     With ``optimize=True`` (the default) the lowered plan is run through the
-    whole-program optimizer pipeline (``plan_opt.optimize_plan``): pjit
+    whole-program optimizer pipeline (``plan_opt.optimize_plan``): jit
     inlining, scan-invariant reshard hoisting, reshard CSE, dead-reshard
     elimination, collective fusion, and overlap-aware scheduling.  The passes
     are semantics-preserving; ``optimize=False`` keeps the raw per-equation
@@ -1691,7 +1696,7 @@ def plan_peak_bytes(plan: PartitionPlan) -> float:
 
     Inputs and consts are resident for the whole step (params are not
     donated); intermediates are allocated at their producing step (each
-    step's ``wbytes``) and freed after their last reader.  ``scan``/``pjit``
+    step's ``wbytes``) and freed after their last reader.  ``scan``/``jit``
     steps add their inner plan's peak as a transient while they run.
     """
     sizes: Dict[int, float] = {}
@@ -1740,7 +1745,7 @@ class PlanCost:
     combined by :func:`repro.analysis.roofline.overlap_time_s` — the dominant
     term bounds the step, the smaller one is mostly hidden behind it.
 
-    ``wire_bytes`` / ``launches`` are **whole-program**: inner pjit/scan plans
+    ``wire_bytes`` / ``launches`` are **whole-program**: inner jit/scan plans
     contribute at trip count (a psum a scan body replays L times costs L
     launches here), matching ``total_flops``'s trip-multiplied compute — this
     is what makes pipeline-loop pricing honest (per-tick ppermute/psum × the
@@ -1829,7 +1834,7 @@ class PlanCost:
 def plan_cost(plan: PartitionPlan) -> PlanCost:
     """Price an already-lowered plan under the roofline cost model.
 
-    Collective terms are whole-program (inner pjit/scan bodies at trip count,
+    Collective terms are whole-program (inner jit/scan bodies at trip count,
     via ``plan_opt.whole_wire_bytes`` / ``whole_collective_launches``) so the
     autoshard objective sees the same cost the overlap scheduler prices — the
     PR 4 open item ("scan-body collectives invisible to the objective") is

@@ -5,14 +5,14 @@ module is the layer that optimizes the *whole* partitioned program before it
 is jitted — the plan-level analogue of GSPMD's CollectivePermute/AllToAll
 compiler optimizations and of the grouped/bucketed collectives production
 partitioners emit.  Since PR 4 the pipeline is *whole-program*: trivial
-``pjit`` call boundaries are dissolved (PartIR-style whole-program lowering)
+``jit`` call boundaries are dissolved (PartIR-style whole-program lowering)
 and loop-invariant reshards leave ``scan`` bodies, so every later pass prices
 and rewrites one flat step list.  ``compile_plan`` runs
 :func:`optimize_plan` by default.
 
 Passes (in pipeline order):
 
-1. **pjit inlining** (:func:`inline_pjit`) — splices a trivial ``pjit`` step's
+1. **jit inlining** (:func:`inline_jit`) — splices a trivial ``jit`` step's
    body (no nested control flow, ≤ ``INLINE_MAX_STEPS`` steps) into the outer
    step list with :class:`~repro.core.plan.ProxyVar` renaming, so
    cross-boundary reshards and collectives become visible to every later
@@ -79,7 +79,7 @@ Pass-ordering invariants
   before-read order, the set of jaxpr-output writes, and ``plan.stats``
   consistency (use ``PlanStats.remove_program`` when deleting a reshard).
 * Passes mutate ``plan.steps`` in place so inner plans captured by
-  pjit/scan closures see the optimized list; :func:`hoist_scan_invariants`
+  jit/scan closures see the optimized list; :func:`hoist_scan_invariants`
   relies on the same aliasing in the other direction when it edits a scan
   body's ``inner.steps``.
 
@@ -111,7 +111,7 @@ plus the plan/optimizer suites before trusting a green bench run.
 
 Every pass reports its savings; :func:`optimize_plan` attaches an
 :class:`OptReport` (whole-program bytes and collective-launch counts
-before/after — inner pjit/scan plans priced at trip count via
+before/after — inner jit/scan plans priced at trip count via
 :func:`whole_wire_bytes` / :func:`whole_collective_launches` — plus per-pass
 detail and the overlap-schedule model) to the plan for the benchmark layer
 (``BENCH_plan.json``).
@@ -137,7 +137,7 @@ from .plan import (
 
 __all__ = [
     "OptReport", "PassReport", "optimize_plan",
-    "inline_pjit", "hoist_scan_invariants",
+    "inline_jit", "hoist_scan_invariants",
     "reshard_cse", "dead_reshard_elim", "sink_output_aliases",
     "fuse_collectives", "schedule_overlap",
     "whole_wire_bytes", "whole_collective_launches",
@@ -157,7 +157,7 @@ def _launch_s(plan: PartitionPlan) -> float:
     p = _plan_params(plan)
     return p.collective_launch_s if p is not None else COLLECTIVE_LAUNCH_S
 
-# Inlining cap: a pjit body longer than this stays a call step.  The point of
+# Inlining cap: a jit body longer than this stays a call step.  The point of
 # the bound is compile time, not correctness — splicing is O(steps), but every
 # spliced step re-enters CSE/fusion/scheduling, and giant bodies (full model
 # layers) rarely share cross-boundary reshards worth the pass time.
@@ -177,7 +177,7 @@ class PassReport:
     fused_buckets: int = 0
     fused_members: int = 0
     launch_s_saved: float = 0.0
-    inlined_bodies: int = 0  # inline-pjit only
+    inlined_bodies: int = 0  # inline-jit only
     hoisted_reshards: int = 0  # scan-hoist only
     moved_steps: int = 0  # overlap-schedule only
     overlap_ratio: float = 1.0  # overlap-schedule only: makespan / serial
@@ -191,7 +191,7 @@ class PassReport:
 class OptReport:
     """Before/after accounting for one run of the pass pipeline.
 
-    Byte/launch counts are *whole-program*: inner pjit/scan plans contribute
+    Byte/launch counts are *whole-program*: inner jit/scan plans contribute
     at trip count (:func:`whole_wire_bytes`), so inlining a body or hoisting
     a per-iteration reshard shows up as a delta instead of moving cost in and
     out of visibility.  ``overlap`` carries the overlap scheduler's model:
@@ -266,7 +266,7 @@ def count_collective_launches(steps: List[PlanStep]) -> int:
 
 def whole_wire_bytes(plan: PartitionPlan) -> float:
     """Modeled wire bytes of one whole-program execution: this plan's steps
-    plus every inner pjit/scan plan's, multiplied by its trip count — the
+    plus every inner jit/scan plan's, multiplied by its trip count — the
     number the inline/hoist passes actually move."""
     total = _wire_bytes(plan)
     for s in plan.steps:
@@ -276,7 +276,7 @@ def whole_wire_bytes(plan: PartitionPlan) -> float:
 
 
 def whole_collective_launches(plan: PartitionPlan) -> int:
-    """Collective launches of one whole-program execution (inner pjit/scan
+    """Collective launches of one whole-program execution (inner jit/scan
     plans at trip count)."""
     total = count_collective_launches(plan.steps)
     for s in plan.steps:
@@ -286,7 +286,7 @@ def whole_collective_launches(plan: PartitionPlan) -> int:
 
 
 # ---------------------------------------------------------------------------------
-# pass 1: pjit inlining
+# pass 1: jit inlining
 # ---------------------------------------------------------------------------------
 
 
@@ -298,11 +298,11 @@ def _const_write_run(val):
 
 
 def _splice_body(step: PlanStep) -> List[PlanStep]:
-    """Rewrite one trivial pjit step's inner plan as outer steps.
+    """Rewrite one trivial jit step's inner plan as outer steps.
 
     Every inner env key is renamed: invars map to the call's operand keys,
     uniquely-produced out keys map straight onto the call's outvars, and all
-    other keys get fresh :class:`ProxyVar`s — mandatory, because two pjit
+    other keys get fresh :class:`ProxyVar`s — mandatory, because two jit
     eqns of the same traced function share jaxpr ``Var`` objects, and
     splicing both bodies unrenamed would collide in the outer env.
     """
@@ -361,21 +361,21 @@ def _splice_body(step: PlanStep) -> List[PlanStep]:
     return spliced
 
 
-def inline_pjit(plan: PartitionPlan) -> PassReport:
-    """Splice trivial pjit bodies into the outer step list.
+def inline_jit(plan: PartitionPlan) -> PassReport:
+    """Splice trivial jit bodies into the outer step list.
 
     Trivial = no nested control flow left in the body (a nested *trivial*
-    pjit was already inlined when the body itself was optimized, so any
+    jit was already inlined when the body itself was optimized, so any
     surviving ``inner`` means scan or a big call) and at most
     ``INLINE_MAX_STEPS`` steps.  Inlined steps keep their ``flops``/``wbytes``
     annotations, so ``total_flops`` is unchanged and ``plan_peak_bytes`` now
     sees the body's intermediates directly instead of a pre-aggregated
     ``transient_bytes`` peak.
     """
-    rep = PassReport("inline-pjit")
+    rep = PassReport("inline-jit")
     out: List[PlanStep] = []
     for step in plan.steps:
-        if (step.kind != "compute" or step.op != "pjit" or step.inner is None
+        if (step.kind != "compute" or step.op != "jit" or step.inner is None
                 or len(step.inner.steps) > INLINE_MAX_STEPS
                 or any(s.inner is not None for s in step.inner.steps)):
             out.append(step)
@@ -564,7 +564,7 @@ def reshard_cse(plan: PartitionPlan) -> PassReport:
     is replaced by a free alias (the env write must still happen).
 
     Reshard sources resolve through free-alias chains to a canonical root
-    (an alias is the same value under another env key), so two inlined pjit
+    (an alias is the same value under another env key), so two inlined jit
     bodies that each route the same param through their own annotate alias
     before gathering it still CSE into one gather.
     """
@@ -981,7 +981,7 @@ def step_features(step: PlanStep, mesh) -> Tuple[float, float, float]:
     (:func:`repro.obs.profile.fit_profile`) regresses measured step times
     against, and the SAME features :func:`_step_durations` divides by the
     roofline constants — so a fitted :class:`RooflineParams` reprices exactly
-    the quantities the fit observed.  Inner pjit/scan plans contribute at
+    the quantities the fit observed.  Inner jit/scan plans contribute at
     trip count, matching :func:`whole_wire_bytes`.
     """
     if step.kind == "reshard" and step.program is not None:
@@ -1011,7 +1011,7 @@ def _step_durations(step: PlanStep, mesh,
     """(compute_s, comm_s) of one step under the roofline constants.
 
     Wire steps occupy the interconnect; compute steps occupy the FLOPs unit;
-    a pjit/scan call step occupies *both* for the duration of its (trip-
+    a jit/scan call step occupies *both* for the duration of its (trip-
     multiplied) inner program, since its internal schedule is opaque here.
     ``params`` swaps in a calibrated machine profile (None = defaults).
     """
@@ -1134,7 +1134,7 @@ def step_class(step: PlanStep) -> str:
     the calibration report (:mod:`repro.obs.calibrate`).
 
     Classes: ``reshard``, ``collective`` (psum family), ``ppermute``,
-    ``fused``, ``call:scan`` / ``call:pjit`` (opaque inner plans), ``guard``
+    ``fused``, ``call:scan`` / ``call:jit`` (opaque inner plans), ``guard``
     (sentinel stat/pack epilogue steps), ``compute`` (everything else).
     """
     if step.kind == "reshard":
@@ -1259,7 +1259,7 @@ def optimize_plan(plan: PartitionPlan,
     """Run the whole-program pass pipeline (inline → hoist → CSE → DCE →
     alias-sink → fusion → overlap-schedule) on ``plan``.
 
-    Mutates ``plan.steps``/``plan.stats`` in place (inner pjit/scan plans are
+    Mutates ``plan.steps``/``plan.stats`` in place (inner jit/scan plans are
     captured by reference in step closures) and attaches an :class:`OptReport`
     with before/after whole-program wire bytes and collective-launch counts
     plus the overlap-schedule model.
@@ -1268,7 +1268,7 @@ def optimize_plan(plan: PartitionPlan,
     coll_before = whole_collective_launches(plan)
     bytes_before = whole_wire_bytes(plan)
     reports = [
-        inline_pjit(plan),
+        inline_jit(plan),
         hoist_scan_invariants(plan),
         reshard_cse(plan),
         dead_reshard_elim(plan),

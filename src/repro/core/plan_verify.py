@@ -39,7 +39,7 @@ compile (it is the default in ``compile_plan`` / ``spmd_partition`` /
     ``plan.peak_bytes`` must match a fresh liveness walk — a step list
     mutated after optimization without repricing fails here.
 
-Inner pjit/scan plans are verified recursively — dataflow/spec/kind checks
+Inner jit/scan plans are verified recursively — dataflow/spec/kind checks
 *and* the byte/peak accounting checks: every inner plan's ``opt_report`` and
 ``peak_bytes`` must match fresh recomputations too (the hoist pass rewrites
 inner step lists after their own ``OptReport`` was recorded, and re-syncs

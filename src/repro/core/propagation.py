@@ -9,7 +9,7 @@ Implements the paper's iterative, priority-based propagation:
 * only-refine updates, so a fixed point is guaranteed;
 * user annotations (``gspmd_annotate`` equations) are preserved verbatim, except
   on their declared ``unspecified_dims`` (partial specification, §3.5);
-* recursion into ``scan`` / ``pjit`` / ``remat`` / ``custom_*`` sub-jaxprs, with a
+* recursion into ``scan`` / ``jit`` / ``remat`` / ``custom_*`` sub-jaxprs, with a
   carry fixed-point for ``scan``.
 
 The result maps every jaxpr variable to a ``Sharding``; ``apply.py`` turns that
@@ -303,7 +303,7 @@ class PropagationResult:
     """Immutable view of a finished propagation: the plan compiler's input.
 
     ``sub`` maps *equation index* (not ``id``) to the inner result for
-    scan/pjit/remat bodies.
+    scan/jit/remat bodies.
     """
 
     jaxpr: excore.Jaxpr

@@ -26,7 +26,7 @@ the dynamic reference partitioner; the compiled-plan path
 as a first-class reshard step, and replays the program on every execution —
 whether the step executes where the builder put it is then the whole-program
 optimizer's business (``core/plan_opt.py``: CSE across call boundaries once
-pjit bodies are inlined, hoisting out of scan bodies, fusion, overlap
+jit bodies are inlined, hoisting out of scan bodies, fusion, overlap
 scheduling).  All dims are assumed evenly divisible (uneven dims are padded
 to multiples beforehand, §4.1 — see sharding.pad_to_multiple).
 """

@@ -1,7 +1,8 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode for validation;
-on a real TPU ``interpret=False`` compiles them to Mosaic.  ``attention`` also
+On the CPU backend the kernels run in the Pallas interpreter (validation
+only); on every other backend they compile to Mosaic, so a kernel that cannot
+compile fails instead of silently interpreting.  ``attention`` also
 adapts the model's padded (B,S,KR,Gl,D) layout to the kernel's (B,H,S,D).
 """
 from __future__ import annotations
@@ -13,8 +14,8 @@ from .flash_attention import flash_attention
 from .ssd_scan import ssd_scan
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
 def attention(q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int = 128):
@@ -22,7 +23,7 @@ def attention(q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int 
     group = q.shape[1] // k.shape[1]
     return flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        group_size=group, interpret=not on_tpu(),
+        group_size=group, interpret=_interpret(),
     )
 
 
@@ -38,4 +39,4 @@ def attention_model_layout(q, k, v, *, causal: bool = True, block_q=128, block_k
 
 
 def ssd(x, dt, B, C, A, *, chunk: int = 128):
-    return ssd_scan(x, dt, B, C, A, chunk=chunk, interpret=not on_tpu())
+    return ssd_scan(x, dt, B, C, A, chunk=chunk, interpret=_interpret())

@@ -1,7 +1,9 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
-# The 512 placeholder CPU devices exist ONLY for the dry-run meshes.
+# ^ MUST come first: jax locks the platform and device count on first init.
+# The dry run is a CPU tool: its 512 placeholder CPU devices exist ONLY for
+# the dry-run meshes, and on a TPU host it must not claim the chip.
 
 """Multi-pod dry-run: ``.lower().compile()`` every (arch × shape × mesh) cell.
 

@@ -339,28 +339,6 @@ def derive_mesh(n_devices: Optional[int] = None,
     return mesh, jmesh
 
 
-def state_partition_specs(cfg, st, opt, tc) -> Dict[str, Any]:
-    """PartitionSpec tree shaped like the train-loop state (params, opt
-    state sharded like params, replicated step) — the restore target specs
-    for a cross-topology checkpoint load."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..models import api
-    from ..models.layers import tree_shapes, tree_specs
-    from ..train.optimizer import opt_state_specs
-
-    tree = api.param_tree(cfg, st)
-    pspecs = tree_specs(tree)
-    ospecs = opt_state_specs(opt, pspecs, tree_shapes(tree))
-    fill = lambda t: jax.tree_util.tree_map(
-        lambda s: s if s is not None else P(),
-        t, is_leaf=lambda x: x is None or isinstance(x, P))
-    spec_state = {"params": fill(pspecs), "opt": fill(ospecs), "step": P()}
-    if tc.compress_grads:
-        spec_state["ef"] = fill(pspecs)
-    return spec_state
-
-
 def specs_by_key(spec_state) -> Dict[str, Any]:
     """Flatten a spec tree to the checkpoint's ``/``-joined leaf keys."""
     flat, _ = ckpt_lib._flatten_with_paths(spec_state)
@@ -569,7 +547,9 @@ class ElasticCoordinator:
         consumed numeric injection, swaps the jitted step, and returns
         ``(state, start_step)`` (``(None, None)`` = no checkpoint: reinit)."""
         from repro import autoshard
-        from ..train.loop import init_state, make_train_step
+        from ..train.loop import (
+            init_state, make_train_step, state_partition_specs,
+        )
 
         t0 = time.perf_counter()
         classes = self._classify(err)

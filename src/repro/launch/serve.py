@@ -11,7 +11,7 @@ import jax
 
 from repro.configs.base import get_strategy
 from repro.configs.registry import default_strategy, get_config
-from repro.launch.train import reduced_config
+from repro.launch.train import enable_compile_cache, reduced_config
 from repro.models import api
 from repro.models.layers import tree_init
 from repro.serve.engine import Engine, Request
@@ -26,6 +26,7 @@ def main(argv=None):
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--requests", type=int, default=6)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced_config(get_config(args.arch), args.reduce)
     st = get_strategy(default_strategy(args.arch))

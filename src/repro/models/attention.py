@@ -13,8 +13,9 @@ production-friendly layout here:
   The waste shows up honestly in the roofline MODEL_FLOPS/HLO_FLOPS ratio.
 
 Attention itself is kv-chunked with an online softmax ("flash-in-XLA") so the
-dry-run never materializes (S, T) score tensors; the Pallas flash kernel
-(kernels/flash_attention.py) is the TPU execution path for the same math.
+dry-run never materializes (S, T) score tensors.  The Pallas flash kernel
+(kernels/flash_attention.py) computes the same math, but no model calls it
+yet: every model runs this jnp path, on the chip too.
 """
 from __future__ import annotations
 

@@ -5,7 +5,7 @@ and the overlap scheduler turns those prices into a modeled timeline; traced
 execution (:mod:`repro.obs.trace`) records what the same steps measured.
 :func:`calibration_report` joins the two by *step class* (the taxonomy from
 ``plan_opt.step_class``: compute / reshard / collective / ppermute / fused /
-guard / call:scan / call:pjit ...) and reports the measured/modeled seconds
+guard / call:scan / call:jit ...) and reports the measured/modeled seconds
 ratio per class.
 
 Reading the ratios: measured spans are host dispatch + (with ``sync``)

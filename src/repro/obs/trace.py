@@ -28,7 +28,7 @@ bounds* on per-step device time, tightest for steps dominated by real
 device work (large collectives, big matmuls) and loosest for tiny ops —
 exactly the bias the per-step-class
 :class:`~repro.obs.calibrate.CalibrationReport` is designed to expose.
-Inner pjit/scan plans execute inside their call step's single span (the
+Inner jit/scan plans execute inside their call step's single span (the
 scan body is one jitted unit; per-trip spans would perturb what they
 measure).
 

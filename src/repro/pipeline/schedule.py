@@ -87,7 +87,7 @@ def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
 
 def plan_ppermute_bytes(plan) -> Tuple[float, int]:
     """(whole-program ppermute wire bytes, launches) of a lowered plan —
-    inner pjit/scan plans at trip count, fused ppermutes included."""
+    inner jit/scan plans at trip count, fused ppermutes included."""
     from repro.core.plan_opt import _collective_step_wire_bytes
 
     total, launches = 0.0, 0

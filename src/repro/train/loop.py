@@ -226,14 +226,30 @@ def init_state(cfg: ModelConfig, st: Strategy, opt: Optimizer, tc: TrainConfig, 
     return state
 
 
+def state_partition_specs(cfg, st, opt, tc) -> Dict[str, Any]:
+    """PartitionSpec tree shaped like the train-loop state (params, opt
+    state sharded like params, replicated step) — the strategy's layout of
+    the state, and the restore target specs for a cross-topology checkpoint
+    load.  Call under the mesh the specs are for: weight specs drop the axes
+    it lacks and the ones that do not divide a dim."""
+    from jax.sharding import PartitionSpec as P
+
+    tree = api.param_tree(cfg, st)
+    pspecs = tree_specs(tree)
+    ospecs = opt_state_specs(opt, pspecs, tree_shapes(tree))
+    fill = lambda t: jax.tree_util.tree_map(
+        lambda s: s if s is not None else P(),
+        t, is_leaf=lambda x: x is None or isinstance(x, P))
+    spec_state = {"params": fill(pspecs), "opt": fill(ospecs), "step": P()}
+    if tc.compress_grads:
+        spec_state["ef"] = fill(pspecs)
+    return spec_state
+
+
 def _ambient_mesh():
     """The ambient concrete jax mesh, or None outside any mesh context."""
-    from ..core.compat import get_abstract_mesh
-
-    m = get_abstract_mesh()
-    if m is None or getattr(m, "empty", True):
-        return None
-    return m if isinstance(m, jax.sharding.Mesh) else None
+    m = jax.sharding.get_mesh()
+    return None if m.empty else m
 
 
 class TrainLoop:
@@ -292,6 +308,16 @@ class TrainLoop:
         data cursor (not the state leaf), so the pipeline resumes exactly
         where the checkpoint left off."""
         state = init_state(self.cfg, self.st, self.opt, self.tc, self.rng)
+        amesh = _ambient_mesh()
+        if amesh is not None:
+            # under a mesh, fresh arrays come out replicated on every device;
+            # lay the state out by the strategy instead
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            specs = state_partition_specs(self.cfg, self.st, self.opt, self.tc)
+            state = jax.device_put(state, jax.tree_util.tree_map(
+                lambda s: NamedSharding(amesh, s), specs,
+                is_leaf=lambda x: isinstance(x, PartitionSpec)))
         start = 0
         if self.tc.ckpt_dir:
             last = ckpt_lib.latest_step(self.tc.ckpt_dir)
@@ -300,7 +326,6 @@ class TrainLoop:
                 # the jitted step's constraints can reshard device-side (the
                 # restarted-on-a-new-mesh path); otherwise plain device_put
                 sharding_for = None
-                amesh = _ambient_mesh()
                 if amesh is not None:
                     from jax.sharding import NamedSharding, PartitionSpec
 

@@ -10,3 +10,9 @@ collect_ignore_glob = (
 )
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+import jax  # noqa: E402
+
+# The entry points' main() turns JAX's persistent compilation cache on; tests
+# that call them must neither read nor write it.
+jax.config.update("jax_enable_compilation_cache", False)

@@ -21,12 +21,11 @@ from repro.launch.elastic import (
     derive_mesh,
     sharding_problem,
     specs_by_key,
-    state_partition_specs,
 )
 from repro.models import api
 from repro.models.layers import tree_init
 from repro.train import checkpoint as ckpt
-from repro.train.loop import TrainConfig, TrainLoop
+from repro.train.loop import TrainConfig, TrainLoop, state_partition_specs
 from repro.train.optimizer import get_optimizer
 
 st = get_strategy("2d_finalized")

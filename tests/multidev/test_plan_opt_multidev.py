@@ -122,7 +122,7 @@ def test_fused_allgather_matches_oracle():
 
 
 def _scan_bodies(closed):
-    """All scan-body jaxprs reachable from ``closed`` (pjit bodies walked)."""
+    """All scan-body jaxprs reachable from ``closed`` (jit bodies walked)."""
     found = []
 
     def walk(jaxpr):
@@ -140,7 +140,7 @@ def _scan_bodies(closed):
 
 
 def test_pjit_inline_fused_psums_bit_identical():
-    """Tentpole acceptance: two pjit bodies each ending in an AllReduce can
+    """Tentpole acceptance: two jit bodies each ending in an AllReduce can
     only share a fusion bucket after inlining dissolves the call boundary —
     and the fused execution is bit-identical to the unoptimized plan."""
 
@@ -162,11 +162,11 @@ def test_pjit_inline_fused_psums_bit_identical():
     got_raw = r_raw(*args)
     plan = _the_plan(r_opt)
     raw_plan = _the_plan(r_raw)
-    # raw: both psums live inside opaque pjit steps — nothing to fuse
-    assert sum(1 for s in raw_plan.steps if s.op == "pjit") == 2
+    # raw: both psums live inside opaque jit steps — nothing to fuse
+    assert sum(1 for s in raw_plan.steps if s.op == "jit") == 2
     assert [s for s in raw_plan.steps if s.kind in ("collective", "fused")] == []
     # optimized: bodies inlined, the two psums share one fused launch
-    assert [s for s in plan.steps if s.op == "pjit"] == []
+    assert [s for s in plan.steps if s.op == "jit"] == []
     fused = [s for s in plan.steps if s.kind == "fused"]
     assert len(fused) == 1 and fused[0].op == "fused-all-reduce"
     assert len(fused[0].reads) == 2

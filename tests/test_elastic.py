@@ -20,9 +20,8 @@ from repro.launch.elastic import (
     derive_mesh,
     sharding_problem,
     specs_by_key,
-    state_partition_specs,
 )
-from repro.train.loop import TrainConfig, TrainLoop
+from repro.train.loop import TrainConfig, TrainLoop, state_partition_specs
 from repro.train.optimizer import get_optimizer
 
 st = get_strategy("2d_finalized")

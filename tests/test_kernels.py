@@ -50,7 +50,7 @@ def test_ssd_sweep(B, S, H, hd, ds, chunk, dtype):
     Bm = jnp.asarray(rng.standard_normal((B, S, ds)) * 0.2, dtype)
     Cm = jnp.asarray(rng.standard_normal((B, S, ds)) * 0.2, dtype)
     A = jnp.asarray(-np.abs(rng.standard_normal((H,))), jnp.float32)
-    got = ssd_scan(x, dt, Bm, Cm, A, chunk=chunk)
+    got = ssd_scan(x, dt, Bm, Cm, A, chunk=chunk, interpret=True)
     ref = ssd_scan_ref(x, dt, Bm, Cm, A, chunk=chunk)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=3e-3, atol=3e-3
@@ -75,5 +75,6 @@ def test_ssd_kernel_matches_sequential_recurrence():
             )
             y_seq[b, t] = np.einsum("hpd,d->hp", state, Cm[b, t])
     got = ssd_scan(jnp.asarray(x), jnp.asarray(dt), jnp.asarray(Bm),
-                   jnp.asarray(Cm), jnp.asarray(A), chunk=32)
+                   jnp.asarray(Cm), jnp.asarray(A), chunk=32,
+                   interpret=True)
     np.testing.assert_allclose(np.asarray(got), y_seq, rtol=2e-4, atol=2e-4)

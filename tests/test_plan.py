@@ -207,6 +207,27 @@ def test_plan_records_collective_stats():
     assert isinstance(stats["collectives"], dict)
 
 
+def test_plan_counts_fallback_equations():
+    """A nested ``jax.jit`` lowers through its own call step (the installed
+    jax names the primitive ``jit``); only the op with no rule — concatenate
+    — is counted as gathered by the fallback."""
+    from repro.core.partitioner import spmd_partition
+
+    jmesh = make_jax_mesh((1, 1), ("x", "y"))
+    m = Mesh.create((1, 1), ("x", "y"))
+    inner = jax.jit(lambda a: a * 2.0)
+
+    def f(a, b):
+        return jnp.concatenate([inner(a), b], axis=1)
+
+    runner = spmd_partition(f, jmesh, m, optimize=False)
+    out = runner(np.ones((2, 2), np.float32), np.ones((2, 2), np.float32))
+    np.testing.assert_array_equal(np.asarray(out)[:, :2], 2.0)
+    (entry,) = runner.plans.values()
+    assert [s.op for s in entry.plan.steps if s.inner is not None] == ["jit"]
+    assert entry.plan.stats.as_dict()["fallbacks"] == {"concatenate": 1}
+
+
 # ---------------------------------------------------------------------------------
 # fallback partial gather (pure analysis)
 # ---------------------------------------------------------------------------------

@@ -300,15 +300,15 @@ def test_bucket_cap_limits_fusion():
 
 
 # ---------------------------------------------------------------------------------
-# pass 1/2: pjit inlining + scan-invariant hoisting (whole-program plans)
+# pass 1/2: jit inlining + scan-invariant hoisting (whole-program plans)
 # ---------------------------------------------------------------------------------
 
 R_ = mesh_split(2, mesh, [-1, -1])
 WSH = mesh_split(2, mesh, ["y", -1])
 
 
-def _two_pjit_shared_gather():
-    """Two pjit bodies each gathering the same param *inside* the body: the
+def _two_jit_shared_gather():
+    """Two jit bodies each gathering the same param *inside* the body: the
     duplicate collective is invisible to the optimizer until inlining."""
 
     def block(x, w):
@@ -326,19 +326,19 @@ def _two_pjit_shared_gather():
 def test_inline_pjit_enables_cross_boundary_cse():
     from repro.core.plan_opt import whole_collective_launches, whole_wire_bytes
 
-    f, avals = _two_pjit_shared_gather()
+    f, avals = _two_jit_shared_gather()
     raw, opt = _plans(f, *avals)
-    # raw: two opaque pjit steps, one in-body gather each
-    pjits = [s for s in raw.steps if s.op == "pjit"]
-    assert len(pjits) == 2
+    # raw: two opaque jit steps, one in-body gather each
+    jits = [s for s in raw.steps if s.op == "jit"]
+    assert len(jits) == 2
     assert all(
         sum(1 for t in s.inner.steps if t.kind == "reshard") == 1
-        for s in pjits
+        for s in jits
     )
     # optimized: bodies spliced, the duplicated gather CSE'd to one launch
-    assert [s for s in opt.steps if s.op == "pjit"] == []
+    assert [s for s in opt.steps if s.op == "jit"] == []
     assert sum(1 for s in opt.steps if s.kind == "reshard") == 1
-    assert _pass(opt, "inline-pjit").inlined_bodies == 2
+    assert _pass(opt, "inline-jit").inlined_bodies == 2
     assert whole_collective_launches(opt) < whole_collective_launches(raw)
     assert whole_wire_bytes(opt) < whole_wire_bytes(raw)
     rep = opt.opt_report
@@ -349,10 +349,10 @@ def test_inline_pjit_enables_cross_boundary_cse():
 
 
 def test_inline_threads_flops_through_spliced_steps():
-    """total_flops must be exact after inlining (the pjit step's aggregate is
+    """total_flops must be exact after inlining (the jit step's aggregate is
     replaced by the constituent steps' own annotations), and the removed call
     step's stale inner-plan transient must not survive anywhere."""
-    f, avals = _two_pjit_shared_gather()
+    f, avals = _two_jit_shared_gather()
     raw, opt = _plans(f, *avals)
     assert opt.total_flops() == pytest.approx(raw.total_flops())
     assert all(s.transient_bytes == 0.0 for s in opt.steps)
@@ -360,7 +360,7 @@ def test_inline_threads_flops_through_spliced_steps():
 
 
 def test_inline_skips_nontrivial_bodies():
-    """A pjit body containing control flow (scan) must stay a call step."""
+    """A jit body containing control flow (scan) must stay a call step."""
 
     def block(x):
         def body(c, _):
@@ -375,9 +375,9 @@ def test_inline_skips_nontrivial_bodies():
         return blk(x) * 2.0
 
     raw, opt = _plans(f, _f32(16, 16))
-    assert [s.op for s in raw.steps if s.op == "pjit"] == ["pjit"]
-    assert [s.op for s in opt.steps if s.op == "pjit"] == ["pjit"]
-    assert _pass(opt, "inline-pjit").inlined_bodies == 0
+    assert [s.op for s in raw.steps if s.op == "jit"] == ["jit"]
+    assert [s.op for s in opt.steps if s.op == "jit"] == ["jit"]
+    assert _pass(opt, "inline-jit").inlined_bodies == 0
 
 
 def _scan_invariant_gather(trips=4):
@@ -630,7 +630,7 @@ def test_opt_report_as_dict_schema():
     assert d["collectives_after"] <= d["collectives_before"]
     assert d["wire_bytes_after"] <= d["wire_bytes_before"]
     assert [p["name"] for p in d["passes"]] == [
-        "inline-pjit", "scan-hoist", "reshard-cse", "dead-reshard-elim",
+        "inline-jit", "scan-hoist", "reshard-cse", "dead-reshard-elim",
         "alias-sink", "collective-fusion", "overlap-schedule",
     ]
     assert d["overlap"] is not None

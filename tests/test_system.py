@@ -11,15 +11,32 @@ import pytest
 def test_train_driver_end_to_end(tmp_path):
     from repro.launch.train import main
 
-    losses = main([
+    losses, step_times = main([
         "--arch", "qwen1.5-0.5b", "--reduce", "16", "--steps", "12",
         "--batch", "4", "--seq", "64", "--ckpt-dir", str(tmp_path / "ck"),
         "--ckpt-every", "6",
     ])
-    assert len(losses) == 12
+    assert len(losses) == len(step_times) == 12
     assert np.mean(losses[-3:]) < np.mean(losses[:3])
     # checkpoint written
     assert any(d.startswith("step_") for d in os.listdir(tmp_path / "ck"))
+
+
+def test_compile_cache_dir_is_placed_from_outside(monkeypatch):
+    from repro.launch.train import DEFAULT_COMPILE_CACHE, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+        assert enable_compile_cache() == "/cache/from/env"
+        assert jax.config.jax_compilation_cache_dir == before  # JAX reads env
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(DEFAULT_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_COMPILE_CACHE)
+        # a fixed directory at the root of the checkout
+        assert (DEFAULT_COMPILE_CACHE.parent / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_serve_driver_end_to_end():

@@ -1,0 +1,64 @@
+"""Compile the Pallas kernels for a described (not attached) TPU v5e chip.
+
+Nothing runs: the TPU compiler lowers each kernel at the published widths of
+the model it serves, which catches what interpret mode cannot (block shapes
+off the (8, 128) tiling, VMEM overuse).  The topology is described inside a
+module fixture, never at import, so every pytest worker collects the same
+tests and only the worker that runs this file loads the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ssd_scan import ssd_scan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "hq,hkv,d",
+    [(16, 16, 64),    # qwen1.5-0.5b: 16 heads x 64
+     (24, 8, 128)],   # phi4-mini-3.8b: GQA 24/8 heads x 128
+    ids=["qwen1.5-0.5b", "phi4-mini-3.8b"],
+)
+def test_flash_attention_compiles_for_v5e(one_chip, hq, hkv, d):
+    S = 4096
+    q = _shape(one_chip, (1, hq, S, d))
+    kv = _shape(one_chip, (1, hkv, S, d))
+    compiled = flash_attention.lower(
+        q, kv, kv, causal=True, group_size=hq // hkv, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_for_v5e(one_chip):
+    # mamba2-130m: d_inner 1536 = 24 heads x 64, d_state 128
+    Bb, S, H, hd, ds = 1, 4096, 24, 64, 128
+    compiled = ssd_scan.lower(
+        _shape(one_chip, (Bb, S, H, hd)),
+        _shape(one_chip, (Bb, S, H)),
+        _shape(one_chip, (Bb, S, ds)),
+        _shape(one_chip, (Bb, S, ds)),
+        _shape(one_chip, (H,), jnp.float32),
+        chunk=128, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
