@@ -80,6 +80,22 @@ def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
     )
 
 
+def eqn_name_stack(eqn):
+    """The name stack (``jax.named_scope`` scopes and ``jvp``/``transpose``
+    transforms) that ``eqn`` was traced under; None when it is empty."""
+    ns = eqn.source_info.name_stack
+    return ns if len(ns) else None
+
+
+def under_name_stack(ns):
+    """Context manager that binds primitives under ``ns``, appended to the
+    current name stack, so that the lowered program's op names carry it."""
+    from jax._src import source_info_util
+
+    return source_info_util.set_name_stack(
+        source_info_util.current_name_stack() + ns)
+
+
 def cost_analysis_dict(compiled):
     """``compiled.cost_analysis()`` as a flat dict (``{}`` when the backend
     reports nothing)."""

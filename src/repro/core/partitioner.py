@@ -45,6 +45,8 @@ import numpy as np
 from jax import core, lax
 from jax.extend import core as excore
 
+from repro.obs.trace import span
+
 from .annotate import annotate_p
 from .compat import shard_map
 from .einsum_rules import partitioned_einsum
@@ -473,6 +475,11 @@ class _CacheEntry:
     call: object  # jitted shard_map over the compiled plan
     plan: object  # PartitionPlan (for stats/reporting)
     build_s: float = 0.0  # trace + propagate + plan lowering (no XLA compile)
+    # seconds of each build phase, by the name of its repro.partition.* span
+    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # seconds of the first call with concrete arrays: lowering, XLA compile
+    # (or compile-cache load) and the enqueue; None until that call
+    first_call_s: Optional[float] = None
 
 
 def _aval_key(a):
@@ -527,6 +534,12 @@ def _jmesh_key(jmesh) -> tuple:
 
 def process_plan_cache_stats() -> PlanCacheStats:
     return _PROCESS_STATS
+
+
+def process_plan_cache_entries() -> List[_CacheEntry]:
+    """The process-level plan cache's entries (their ``build_s``,
+    ``phases`` and ``first_call_s``), oldest first."""
+    return list(_PROCESS_CACHE.values())
 
 
 def clear_process_plan_cache() -> None:
@@ -598,7 +611,11 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
     emits a ``profile_applied`` control event.
 
     The returned runner exposes ``runner.cache_stats`` (hits/misses) and
-    ``runner.plans`` (cache-key → PartitionPlan) for tests and reporting.
+    ``runner.plans`` (cache-key → cache entry) for tests and reporting.
+    Each call runs inside the profiler spans ``repro.partition.*`` listed in
+    :mod:`repro.obs.trace`; an entry keeps its build seconds (``build_s``,
+    and per phase ``phases``) and ``first_call_s``, the seconds of its first
+    call with concrete arrays (lowering and XLA compile).
     """
     if guard is not None and not compile_plans:
         raise ValueError("spmd_partition: guard= requires compile_plans=True")
@@ -614,7 +631,9 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         process_cache = False  # tracer is runner-local; sharing a traced
         # entry across call sites would cross-wire their spans
     cache: Dict[tuple, _CacheEntry] = {}
-    stats = PlanCacheStats(scope="runner")
+    # runner-local: a hit is counted on every call, so it stays off the
+    # process-wide metrics registry (the process cache feeds it per build)
+    stats = PlanCacheStats()
 
     def _build(args):
         from repro.obs.profile import resolve_profile
@@ -623,7 +642,9 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         # the digest keys the process cache (None = default constants)
         prof = resolve_profile(profile)
         t0 = time.perf_counter()
-        closed = jax.make_jaxpr(fn)(*args)
+        phases: Dict[str, float] = {}
+        with span("repro.partition.make_jaxpr", phases):
+            closed = jax.make_jaxpr(fn)(*args)
         pkey: Optional[tuple] = None
         if process_cache:
             pkey = (
@@ -642,7 +663,8 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         if autoshard is not None:
             from repro.autoshard.api import solve_jaxpr_cached
 
-            shard_res = solve_jaxpr_cached(closed, mesh, autoshard)
+            with span("repro.partition.autoshard", phases):
+                shard_res = solve_jaxpr_cached(closed, mesh, autoshard)
             if not shard_res.evaluation.feasible:
                 # never silently drop the caller's constraints (e.g. an
                 # unmeetable memory budget) — fall back explicitly instead
@@ -653,7 +675,8 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
                     "(top_n / sa_steps / max_candidates)"
                 )
             in_seeds = shard_res.assignment
-        prop = propagate(closed, mesh, in_shardings=in_seeds)
+        with span("repro.partition.propagate", phases):
+            prop = propagate(closed, mesh, in_shardings=in_seeds)
         in_specs = tuple(
             to_partition_spec(prop.get(v) or replicated(mesh, v.aval.ndim))
             for v in closed.jaxpr.invars
@@ -666,9 +689,10 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         if compile_plans:
             from .plan import compile_plan
 
-            plan = compile_plan(closed, prop.result(), mesh,
-                                optimize=optimize, verify=verify, guard=guard,
-                                profile=prof)
+            with span("repro.partition.compile_plan", phases):
+                plan = compile_plan(closed, prop.result(), mesh,
+                                    optimize=optimize, verify=verify,
+                                    guard=guard, profile=prof, phases=phases)
             if prof is not None:
                 from repro.obs.trace import control_event
 
@@ -697,32 +721,47 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
                 outs = part.run(closed.jaxpr, closed.consts, *local_args)
                 return outs if len(outs) > 1 else outs[0]
 
-        shmapped = shard_map(
-            local_fn,
-            mesh=jmesh,
-            in_specs=in_specs,
-            out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        )
-        # measured tracing skips jit: eager shard_map keeps the Python step
-        # walk alive at run time so per-step timers observe real dispatch
-        # (the whole point — see the tracing contract in repro.obs.trace)
-        traced_eager = tracer is not None and tracer.config.measured
-        entry = _CacheEntry(shmapped if traced_eager else jax.jit(shmapped),
-                            plan, time.perf_counter() - t0)
+        with span("repro.partition.jit", phases):
+            shmapped = shard_map(
+                local_fn,
+                mesh=jmesh,
+                in_specs=in_specs,
+                out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
+            )
+            # measured tracing skips jit: eager shard_map keeps the Python
+            # step walk alive at run time so per-step timers observe real
+            # dispatch (the whole point — see the tracing contract in
+            # repro.obs.trace)
+            traced_eager = tracer is not None and tracer.config.measured
+            call = shmapped if traced_eager else jax.jit(shmapped)
+        entry = _CacheEntry(call, plan, time.perf_counter() - t0, phases)
         if pkey is not None:
             _PROCESS_CACHE[pkey] = entry
         return entry
 
     def runner(*args):
-        key = (mesh.structural_key(), tuple(_aval_key(a) for a in args))
-        entry = cache.get(key)
+        with span("repro.partition.call"):
+            return _call(args)
+
+    def _call(args):
+        with span("repro.partition.lookup"):
+            key = (mesh.structural_key(), tuple(_aval_key(a) for a in args))
+            entry = cache.get(key)
         if entry is None:
             stats.record_miss()
-            entry = _build(args)
+            with span("repro.partition.build"):
+                entry = _build(args)
             cache[key] = entry
         else:
             stats.record_hit()
-        outs = entry.call(*args)
+        with span("repro.partition.dispatch"):
+            if entry.first_call_s is None and not any(
+                    isinstance(a, core.Tracer) for a in args):
+                t0 = time.perf_counter()
+                outs = entry.call(*args)
+                entry.first_call_s = time.perf_counter() - t0
+            else:
+                outs = entry.call(*args)
         if guard is not None and entry.plan is not None \
                 and entry.plan.guard is not None:
             from .plan import NumericsFault, guard_faults

@@ -72,6 +72,7 @@ from .annotate import annotate_p
 from .collective_planner import (
     PlanError, ReshardProgram, execute_program, plan_reshard,
 )
+from .compat import eqn_name_stack, under_name_stack
 from .einsum_rules import compile_einsum, execute_einsum
 from .propagation import Propagation, PropagationResult
 from .reshard import shard_shape
@@ -146,6 +147,10 @@ class PlanStep:
     # {"num_consts", "num_carry"} for scan.
     inner: Optional["PartitionPlan"] = None
     call: Dict = dataclasses.field(default_factory=dict)
+    # the source equation's name stack (its ``jax.named_scope`` scopes and
+    # jvp/transpose transforms), which execute() binds the step under so the
+    # partitioned program's op names keep them; None binds under the caller's
+    name_stack: Optional[object] = None
 
     @property
     def in_bytes(self) -> float:
@@ -307,8 +312,9 @@ class PartitionPlan:
         ``tracer`` (an :class:`repro.obs.trace.Tracer`) switches to the
         traced walk — per-step measured spans, only meaningful under eager
         (non-jitted) shard_map; see the tracing contract in
-        :mod:`repro.obs.trace`.  The untraced path is untouched: no timer
-        reads, no extra attribute lookups per step.
+        :mod:`repro.obs.trace`.  The untraced path reads no timers; it binds
+        each step under the step's name stack, which under ``jit`` costs
+        trace time only.
         """
         if tracer is not None:
             return self._execute_traced(args, tracer)
@@ -318,7 +324,11 @@ class PartitionPlan:
         for v, a in zip(self.jaxpr.invars, args):
             env[v] = a
         for step in self.steps:
-            step.run(env, step.reads, step.writes)
+            if step.name_stack is None:
+                step.run(env, step.reads, step.writes)
+            else:
+                with under_name_stack(step.name_stack):
+                    step.run(env, step.reads, step.writes)
         return tuple(_read(env, k) for k in self.out_keys)
 
     def _execute_traced(self, args, tracer):
@@ -868,7 +878,13 @@ class PlanBuilder:
         in_shardings = [self.sh[v] for v in self.jaxpr.invars]
         for idx, eqn in enumerate(self.jaxpr.eqns):
             self.stats.eqns += 1
+            first = len(self.steps)
             self.eqn(idx, eqn)
+            # every step emitted for the equation (its reshards and trailing
+            # collectives too) carries the equation's scopes
+            ns = eqn_name_stack(eqn)
+            for step in self.steps[first:]:
+                step.name_stack = ns
         # output epilogue: reshards to the propagated output shardings are
         # first-class steps writing proxy keys, so CSE/DCE/fusion price them
         out_shardings: List[Sharding] = []
@@ -1630,6 +1646,7 @@ def compile_plan(
     verify: Optional[bool] = None,
     guard: Optional[GuardConfig] = None,
     profile: Optional[object] = None,
+    phases: Optional[Dict[str, float]] = None,
 ) -> PartitionPlan:
     """Lower a propagated (closed) jaxpr into an executable PartitionPlan.
 
@@ -1655,23 +1672,31 @@ def compile_plan(
     optimization, so the overlap scheduler, fusion-bucket sizing, and every
     downstream :class:`PlanCost` price with the fitted machine constants.
     ``None`` keeps the module-default constants bit-identically.
+
+    The lowering, the optimizer passes and the verifier run inside the
+    profiler spans ``repro.partition.{lower,optimize,verify}``; ``phases``,
+    where given, gets their seconds under those three names.
     """
+    from repro.obs.trace import span
+
     from .collective_planner import thread_search_telemetry
 
     t0 = thread_search_telemetry()
-    builder = PlanBuilder(
-        closed.jaxpr, closed.consts, prop, mesh, optimize=optimize,
-        cost_only=cost_only,
-    )
-    plan = builder.build()
-    if profile is not None:
-        plan.params = profile
-    if guard is not None:
-        append_guard_steps(plan, guard, cost_only=cost_only)
+    with span("repro.partition.lower", phases):
+        builder = PlanBuilder(
+            closed.jaxpr, closed.consts, prop, mesh, optimize=optimize,
+            cost_only=cost_only,
+        )
+        plan = builder.build()
+        if profile is not None:
+            plan.params = profile
+        if guard is not None:
+            append_guard_steps(plan, guard, cost_only=cost_only)
     if optimize:
         from .plan_opt import optimize_plan
 
-        plan = optimize_plan(plan)
+        with span("repro.partition.optimize", phases):
+            plan = optimize_plan(plan)
     elif guard is not None:
         # build() priced the peak before the guard epilogue existed
         plan.peak_bytes = plan_peak_bytes(plan)
@@ -1682,7 +1707,8 @@ def compile_plan(
     if verify_enabled(verify):
         from .plan_verify import verify_plan
 
-        verify_plan(plan)
+        with span("repro.partition.verify", phases):
+            verify_plan(plan)
     return plan
 
 

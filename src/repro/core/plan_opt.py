@@ -297,6 +297,13 @@ def _const_write_run(val):
     return run
 
 
+def _join_name_stacks(outer, inner):
+    """The name stack of a body step moved out of its call step."""
+    if outer is None or inner is None:
+        return inner if outer is None else outer
+    return outer + inner
+
+
 def _splice_body(step: PlanStep) -> List[PlanStep]:
     """Rewrite one trivial jit step's inner plan as outer steps.
 
@@ -305,8 +312,10 @@ def _splice_body(step: PlanStep) -> List[PlanStep]:
     other keys get fresh :class:`ProxyVar`s — mandatory, because two jit
     eqns of the same traced function share jaxpr ``Var`` objects, and
     splicing both bodies unrenamed would collide in the outer env.
+    Spliced steps run under the call's name stack followed by their own.
     """
     inner = step.inner
+    outer_ns = step.name_stack
     ren: Dict[int, object] = {}
     for iv, outer_key in zip(inner.jaxpr.invars, step.reads):
         ren[id(iv)] = outer_key
@@ -316,7 +325,7 @@ def _splice_body(step: PlanStep) -> List[PlanStep]:
         ren[id(cv)] = p
         spliced.append(PlanStep(
             "compute", (), (p,), _const_write_run(c), op="const",
-            wbytes=(float(np.asarray(c).nbytes),),
+            wbytes=(float(np.asarray(c).nbytes),), name_stack=outer_ns,
         ))
     # outputs: an out key written by the body and not yet mapped takes the
     # outer outvar as its name; literals, passthrough inputs/consts, and
@@ -344,7 +353,9 @@ def _splice_body(step: PlanStep) -> List[PlanStep]:
                 nk = ProxyVar(f"inline.{s.op or s.kind}")
                 ren[id(w)] = nk
             writes.append(nk)
-        ns = dataclasses.replace(s, reads=reads, writes=tuple(writes))
+        ns = dataclasses.replace(s, reads=reads, writes=tuple(writes),
+                                 name_stack=_join_name_stacks(outer_ns,
+                                                              s.name_stack))
         if hasattr(s, "_wire_bytes"):
             ns._wire_bytes = s._wire_bytes  # noqa: SLF001 - fused-step annotation
         spliced.append(ns)
@@ -352,11 +363,12 @@ def _splice_body(step: PlanStep) -> List[PlanStep]:
         if isinstance(ik, excore.Literal):
             spliced.append(PlanStep(
                 "compute", (), (ov,), _const_write_run(ik.val), op="const",
-                wbytes=(float(np.asarray(ik.val).nbytes),),
+                wbytes=(float(np.asarray(ik.val).nbytes),), name_stack=outer_ns,
             ))
         else:
             spliced.append(PlanStep(
                 "compute", (ren.get(id(ik), ik),), (ov,), _alias_run, op="alias",
+                name_stack=outer_ns,
             ))
     return spliced
 
@@ -469,6 +481,7 @@ def hoist_scan_invariants(plan: PartitionPlan) -> PassReport:
             proxy = ProxyVar("hoist.const")
             out.append(dataclasses.replace(
                 rs, reads=(new_reads[i],), writes=(proxy,),
+                name_stack=_join_name_stacks(step.name_stack, rs.name_stack),
             ))
             new_reads[i] = proxy
             # body consumers of the reshard result now read its (aliased)

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span
+
 
 @dataclasses.dataclass
 class DataConfig:
@@ -38,28 +40,30 @@ class TokenPipeline:
             self._mm = np.memmap(cfg.path, dtype=np.int32, mode="r")
 
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:
-        """The batch for global step ``step`` (deterministic)."""
-        c = self.cfg
-        B, S = self.local_batch, c.seq_len
-        row0 = step * c.global_batch + self.process_index * B
-        if self._mm is not None:
-            need = B * (S + 1)
-            start = (row0 * (S + 1)) % max(len(self._mm) - need, 1)
-            flat = np.asarray(self._mm[start : start + need])
-            toks = flat.reshape(B, S + 1)
-        elif c.pattern == "arithmetic":
-            # fully learnable: token[t+1] = (token[t] + stride) mod V
-            rng = np.random.default_rng(c.seed + step * 1000 + self.process_index)
-            start = rng.integers(0, c.vocab_size, (B, 1))
-            stride = rng.integers(1, 17, (B, 1))
-            toks = ((start + stride * np.arange(S + 1)) % c.vocab_size).astype(np.int32)
-        else:
-            key = jax.random.fold_in(jax.random.PRNGKey(c.seed), step)
-            key = jax.random.fold_in(key, self.process_index)
-            toks = np.asarray(
-                jax.random.randint(key, (B, S + 1), 0, c.vocab_size, jnp.int32)
-            )
-        return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+        """The batch for global step ``step`` (deterministic), made inside
+        the profiler span ``repro.data.batch_at``."""
+        with span("repro.data.batch_at"):
+            c = self.cfg
+            B, S = self.local_batch, c.seq_len
+            row0 = step * c.global_batch + self.process_index * B
+            if self._mm is not None:
+                need = B * (S + 1)
+                start = (row0 * (S + 1)) % max(len(self._mm) - need, 1)
+                flat = np.asarray(self._mm[start : start + need])
+                toks = flat.reshape(B, S + 1)
+            elif c.pattern == "arithmetic":
+                # fully learnable: token[t+1] = (token[t] + stride) mod V
+                rng = np.random.default_rng(c.seed + step * 1000 + self.process_index)
+                start = rng.integers(0, c.vocab_size, (B, 1))
+                stride = rng.integers(1, 17, (B, 1))
+                toks = ((start + stride * np.arange(S + 1)) % c.vocab_size).astype(np.int32)
+            else:
+                key = jax.random.fold_in(jax.random.PRNGKey(c.seed), step)
+                key = jax.random.fold_in(key, self.process_index)
+                toks = np.asarray(
+                    jax.random.randint(key, (B, S + 1), 0, c.vocab_size, jnp.int32)
+                )
+            return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         step = 0

@@ -76,9 +76,13 @@ def pipeline_boundary(cfg: ModelConfig, st: Strategy) -> Optional[PipelineBounda
     if not cfg.stackable_layers:
         return None
 
+    # the embedding and the LM head with its loss, under the scope the
+    # unpipelined model gives them (transformer.forward)
+    @jax.named_scope("head")
     def prologue(params, tokens):
         return embed_lookup(cfg, st, params["embed"], tokens)
 
+    @jax.named_scope("head")
     def epilogue(params, x, batch):
         x = rms_norm(x, params["final_ln"])
         if cfg.xent_chunk:

@@ -71,35 +71,43 @@ def param_tree(cfg: ModelConfig, st: Strategy):
 
 
 def decoder_layer(cfg: ModelConfig, st: Strategy, lp: Params, x, positions):
-    """Returns (x, aux_loss)."""
+    """Returns (x, aux_loss).  The two sublayers run under the scopes
+    ``attention`` and ``mlp``, which the program's op names keep (their
+    backward under ``transpose(...)``), so a device trace can time each."""
     from .moe import moe_forward
 
-    if cfg.gather_norm_input:
-        # §Perf: gather a bf16 COPY of the residual for the layer (instead of
-        # XLA gathering the f32 norm input); the carry itself stays sharded.
-        h_src = st.constrain(x, "batch", "seq", None)
-    else:
-        h_src = x
-    h = rms_norm(h_src, lp["ln1"])
-    h = attn.self_attention(cfg, st, lp["attn"], h, positions, causal=cfg.causal)
+    with jax.named_scope("attention"):
+        if cfg.gather_norm_input:
+            # §Perf: gather a bf16 COPY of the residual for the layer (instead
+            # of XLA gathering the f32 norm input); the carry stays sharded.
+            h_src = st.constrain(x, "batch", "seq", None)
+        else:
+            h_src = x
+        h = rms_norm(h_src, lp["ln1"])
+        h = attn.self_attention(cfg, st, lp["attn"], h, positions,
+                                causal=cfg.causal)
     x = st.constrain(x + h, "batch", "seq", "embed")
-    h_src = st.constrain(x, "batch", "seq", None) if cfg.gather_norm_input else x
-    h = rms_norm(h_src, lp["ln2"])
-    aux = jnp.zeros((), jnp.float32)
-    if "moe" in lp:
-        y, aux = moe_forward(cfg, st, lp["moe"], h)
-        if "mlp" in lp:
-            y = y + mlp_forward(cfg, st, lp["mlp"], h)
-    else:
-        y = mlp_forward(cfg, st, lp["mlp"], h)
+    with jax.named_scope("mlp"):
+        h_src = (st.constrain(x, "batch", "seq", None)
+                 if cfg.gather_norm_input else x)
+        h = rms_norm(h_src, lp["ln2"])
+        aux = jnp.zeros((), jnp.float32)
+        if "moe" in lp:
+            y, aux = moe_forward(cfg, st, lp["moe"], h)
+            if "mlp" in lp:
+                y = y + mlp_forward(cfg, st, lp["mlp"], h)
+        else:
+            y = mlp_forward(cfg, st, lp["mlp"], h)
     return st.constrain(x + y, "batch", "seq", "embed"), aux
 
 
 def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
-    """tokens (B,S) -> (logits (B,S,V), aux_loss)."""
+    """tokens (B,S) -> (logits (B,S,V), aux_loss).  The embedding, the final
+    norm and the LM head run under the scope ``head``."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    x = embed_lookup(cfg, st, params["embed"], tokens)
+    with jax.named_scope("head"):
+        x = embed_lookup(cfg, st, params["embed"], tokens)
 
     sb = superblock(cfg)
 
@@ -117,15 +125,18 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
         layer_fn, params["layers"], (x, jnp.zeros((), jnp.float32)), cfg,
         extra=positions,
     )
-    x = rms_norm(x, params["final_ln"])
-    return unembed_logits(cfg, st, params["embed"], x), aux
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_ln"])
+        return unembed_logits(cfg, st, params["embed"], x), aux
 
 
 def backbone(cfg: ModelConfig, st: Strategy, params: Params, tokens):
-    """Embedding + layer stack + final norm (pre-logits)."""
+    """Embedding + layer stack + final norm (pre-logits); the embedding and
+    the final norm run under the scope ``head``."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    x = embed_lookup(cfg, st, params["embed"], tokens)
+    with jax.named_scope("head"):
+        x = embed_lookup(cfg, st, params["embed"], tokens)
     sb = superblock(cfg)
 
     def layer_fn(lp, carry, extra):
@@ -142,20 +153,24 @@ def backbone(cfg: ModelConfig, st: Strategy, params: Params, tokens):
         layer_fn, params["layers"], (x, jnp.zeros((), jnp.float32)), cfg,
         extra=positions,
     )
-    return rms_norm(x, params["final_ln"]), aux
+    with jax.named_scope("head"):
+        return rms_norm(x, params["final_ln"]), aux
 
 
 def loss_fn(cfg: ModelConfig, st: Strategy, params: Params, batch, aux_coef=0.01):
+    """Cross entropy (under the scope ``head``) plus the weighted aux loss."""
     if cfg.xent_chunk:
         from .layers import streamed_xent
 
         x, aux = backbone(cfg, st, params, batch["tokens"])
-        return (
-            streamed_xent(cfg, st, x, params["embed"]["embedding"], batch["labels"])
-            + aux_coef * aux
-        )
+        with jax.named_scope("head"):
+            xent = streamed_xent(cfg, st, x, params["embed"]["embedding"],
+                                 batch["labels"])
+        return xent + aux_coef * aux
     logits, aux = forward(cfg, st, params, batch["tokens"])
-    return softmax_xent(cfg, st, logits, batch["labels"]) + aux_coef * aux
+    with jax.named_scope("head"):
+        xent = softmax_xent(cfg, st, logits, batch["labels"])
+    return xent + aux_coef * aux
 
 
 # ---------------------------------------------------------------------------------

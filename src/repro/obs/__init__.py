@@ -10,7 +10,11 @@ Three layers over the compiled-plan runtime (the GSPMD repro's answer to
   autoshard search/eval timing, elastic fault/skip/rewind counters) all land
   in — or are joined into — a single :func:`~repro.obs.metrics.snapshot`,
   dumpable as JSON (``REPRO_METRICS_DUMP=path``).
-* :mod:`repro.obs.trace` — opt-in traced execution for compiled plans
+* :mod:`repro.obs.trace` — :func:`~repro.obs.trace.span`, the ``repro.*``
+  host spans on the profiler's clock (the runner, its plan build and first
+  compile, the input pipeline), which with the scopes the partitioned
+  program keeps are what time the jitted program in a device trace; and
+  opt-in traced execution for compiled plans
   (``spmd_partition(trace=TraceConfig(...))``): per-step measured spans on
   the two lanes the overlap scheduler models (compute / interconnect), a
   *modeled* timeline emitted straight from the overlap schedule, and elastic
@@ -58,6 +62,7 @@ from .trace import (
     export_control_trace,
     recovery_narrative,
     reset_control_events,
+    span,
     validate_trace_events,
 )
 
@@ -84,5 +89,6 @@ __all__ = [
     "reset_control_events",
     "resolve_profile",
     "snapshot",
+    "span",
     "validate_trace_events",
 ]

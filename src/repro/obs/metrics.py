@@ -3,9 +3,11 @@
 One process-wide :class:`MetricsRegistry` (:func:`registry`) replaces the
 five ad-hoc telemetry surfaces that grew across PRs 1–7:
 
-* plan-cache hit/miss counters (``core.partitioner.PlanCacheStats``) — every
-  ``record_hit``/``record_miss`` now also lands in ``plan_cache.<scope>.*``
-  counters here;
+* plan-cache hit/miss counters (``core.partitioner.PlanCacheStats``) — a
+  cache with a ``scope`` (the process-level plan cache, which moves once per
+  plan build) also lands its hits and misses in ``plan_cache.<scope>.*``
+  counters here; a runner's own cache, hit on every call, stays off the
+  registry and is read from ``runner.cache_stats``;
 * lattice-search counters (``core.collective_planner.search_telemetry``) and
 * static-verifier telemetry (``core.plan_verify.verify_telemetry``) — joined
   into every :func:`snapshot` as read-only *sources* (their modules stay the
@@ -14,7 +16,7 @@ five ad-hoc telemetry surfaces that grew across PRs 1–7:
   histograms and ``autoshard.solves`` / ``autoshard.evals`` counters
   (``autoshard/api.py`` / ``autoshard/evaluate.py``);
 * train/elastic counters — ``train.guard.{faults,skips,rewinds}``,
-  ``train.step_ms`` / ``train.tokens_per_s`` histograms (``train/loop.py``),
+  the ``train.step_ms`` histogram (``train/loop.py``),
   ``elastic.*`` recovery counters (``launch/elastic.py``).
 
 Everything is stdlib-only and import-light: core modules may import this

@@ -1,4 +1,37 @@
-"""Plan-step tracing: measured spans, modeled timelines, control events.
+"""Plan-step tracing: profiler spans, measured spans, modeled timelines,
+control events.
+
+Profiler spans
+--------------
+
+:func:`span` is the one helper for host spans on the profiler's clock: a
+``jax.profiler.TraceAnnotation`` that lands on the host plane of the same
+``.xplane.pb`` as the device operations, so device idle time can be laid
+against what the host was doing.  It costs an inactive ``TraceMe`` until a
+profiler session runs, so the spans are always on.  These spans, and the
+scopes the partitioned program carries into the device trace (each plan step
+is bound under its source equation's name stack), are what time the jitted
+program; the *measured* lane below times an eager replay of it instead.
+
+=============================  =================================================
+span                           what it covers
+=============================  =================================================
+``repro.partition.call``       one ``spmd_partition`` runner call, with children
+``repro.partition.lookup``     the cache key and the runner's plan cache
+``repro.partition.build``      a plan build (a cache miss), with children
+                               ``make_jaxpr``, ``autoshard``, ``propagate``,
+                               ``compile_plan`` (itself ``lower``, ``optimize``,
+                               ``verify``) and ``jit``, each
+                               ``repro.partition.<phase>``; their seconds land
+                               in ``_CacheEntry.phases``
+``repro.partition.dispatch``   the jitted program's call: on the entry's first
+                               call with concrete arrays, its lowering and XLA
+                               compile (``_CacheEntry.first_call_s``)
+``repro.data.batch_at``        ``TokenPipeline.batch_at``
+=============================  =================================================
+
+``TrainLoop.run`` also wraps each step in
+``jax.profiler.StepTraceAnnotation("train", step_num=step)``.
 
 Tracing contract (read this before trusting a number)
 -----------------------------------------------------
@@ -85,6 +118,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 # One perf_counter epoch per process: measured spans and control events share
 # it, so cross-source ordering in the merged trace is meaningful.
 _EPOCH = time.perf_counter()
@@ -103,6 +138,36 @@ _COMM_KINDS = ("reshard", "collective", "fused")
 
 def _now_us() -> float:
     return (time.perf_counter() - _EPOCH) * 1e6
+
+
+class _TimedSpan:
+    """A profiler span that also adds its host seconds to ``times``."""
+
+    __slots__ = ("_annotation", "_times", "_key", "_t0")
+
+    def __init__(self, name: str, times: Dict[str, float]):
+        self._annotation = TraceAnnotation(name)
+        self._times = times
+        self._key = name.rsplit(".", 1)[-1]
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._times[self._key] = self._times.get(self._key, 0.0) + dt
+        return self._annotation.__exit__(*exc)
+
+
+def span(name: str, times: Optional[Dict[str, float]] = None):
+    """Context manager: a host span ``name`` on the profiler's clock (see
+    "Profiler spans" above).  With ``times``, the span's seconds are also
+    added to ``times`` under the name's last dotted part."""
+    if times is None:
+        return TraceAnnotation(name)
+    return _TimedSpan(name, times)
 
 
 @dataclass(frozen=True)

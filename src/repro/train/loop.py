@@ -130,47 +130,50 @@ def make_train_step(cfg: ModelConfig, st: Strategy, opt: Optimizer, tc: TrainCon
                 _fault_window(step, nf.grad_spike_at_step, nf.steps),
                 jnp.float32(nf.spike_factor), 1.0)
             grads = jax.tree_util.tree_map(lambda g: g * spike, grads)
-        if tc.compress_grads:
-            # half-precision gradient exchange with error feedback: quantize to
-            # bf16 (halves ReduceScatter bytes), remember the residual in fp32.
-            ef = state["ef"]
-            grads = jax.tree_util.tree_map(jnp.add, grads, ef)
-            q = jax.tree_util.tree_map(lambda g: g.astype(jnp.bfloat16), grads)
-            new_ef = jax.tree_util.tree_map(
-                lambda g, qq: g - qq.astype(jnp.float32), grads, q
-            )
-            grads = jax.tree_util.tree_map(lambda qq: qq.astype(jnp.float32), q)
-        new_params, new_opt = opt.update(grads, opt_state, params, step)
-        gnorm = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
-        )
-        new_state = {"params": new_params, "opt": new_opt, "step": step + 1}
-        if tc.compress_grads:
-            new_state["ef"] = new_ef
-        metrics = {"loss": loss, "grad_norm": gnorm}
-        gc = tc.guard
-        if gc is not None:
-            stats = [_guard_stat(x) for _, x in
-                     _guard_tensors(gc, loss, grads, new_opt)]
-            gvec = jnp.stack(stats)  # (k, 2): [nonfinite_count, absmax]
-            fault = jnp.any(gvec[:, 0] > 0) | jnp.any(~jnp.isfinite(gvec[:, 1]))
-            if np.isfinite(gc.max_abs):
-                fault = fault | jnp.any(gvec[:, 1] > gc.max_abs)
-            if np.isfinite(gc.max_grad_norm):
-                fault = fault | ~jnp.isfinite(gnorm) | (gnorm > gc.max_grad_norm)
-            # skip-in-jit: keep old params/opt/ef on fault so the poisoned
-            # update never lands; the step counter still advances (the data
-            # cursor moves past the bad batch)
-            keep = lambda old, new: jnp.where(fault, old, new)
-            new_state["params"] = jax.tree_util.tree_map(
-                keep, params, new_state["params"])
-            new_state["opt"] = jax.tree_util.tree_map(
-                keep, opt_state, new_state["opt"])
+        # the update and the step's norm, clip and guard math: one scope
+        # in the program's op names, so a device trace can time it
+        with jax.named_scope("optimizer"):
             if tc.compress_grads:
-                new_state["ef"] = jax.tree_util.tree_map(
-                    keep, state["ef"], new_state["ef"])
-            metrics["guard"] = gvec.reshape(-1)
-            metrics["fault"] = fault
+                # half-precision gradient exchange with error feedback: quantize to
+                # bf16 (halves ReduceScatter bytes), remember the residual in fp32.
+                ef = state["ef"]
+                grads = jax.tree_util.tree_map(jnp.add, grads, ef)
+                q = jax.tree_util.tree_map(lambda g: g.astype(jnp.bfloat16), grads)
+                new_ef = jax.tree_util.tree_map(
+                    lambda g, qq: g - qq.astype(jnp.float32), grads, q
+                )
+                grads = jax.tree_util.tree_map(lambda qq: qq.astype(jnp.float32), q)
+            new_params, new_opt = opt.update(grads, opt_state, params, step)
+            gnorm = jnp.sqrt(
+                sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree_util.tree_leaves(grads))
+            )
+            new_state = {"params": new_params, "opt": new_opt, "step": step + 1}
+            if tc.compress_grads:
+                new_state["ef"] = new_ef
+            metrics = {"loss": loss, "grad_norm": gnorm}
+            gc = tc.guard
+            if gc is not None:
+                stats = [_guard_stat(x) for _, x in
+                         _guard_tensors(gc, loss, grads, new_opt)]
+                gvec = jnp.stack(stats)  # (k, 2): [nonfinite_count, absmax]
+                fault = jnp.any(gvec[:, 0] > 0) | jnp.any(~jnp.isfinite(gvec[:, 1]))
+                if np.isfinite(gc.max_abs):
+                    fault = fault | jnp.any(gvec[:, 1] > gc.max_abs)
+                if np.isfinite(gc.max_grad_norm):
+                    fault = fault | ~jnp.isfinite(gnorm) | (gnorm > gc.max_grad_norm)
+                # skip-in-jit: keep old params/opt/ef on fault so the poisoned
+                # update never lands; the step counter still advances (the data
+                # cursor moves past the bad batch)
+                keep = lambda old, new: jnp.where(fault, old, new)
+                new_state["params"] = jax.tree_util.tree_map(
+                    keep, params, new_state["params"])
+                new_state["opt"] = jax.tree_util.tree_map(
+                    keep, opt_state, new_state["opt"])
+                if tc.compress_grads:
+                    new_state["ef"] = jax.tree_util.tree_map(
+                        keep, state["ef"], new_state["ef"])
+                metrics["guard"] = gvec.reshape(-1)
+                metrics["fault"] = fault
         return new_state, metrics
 
     return step_fn
@@ -358,85 +361,84 @@ class TrainLoop:
                 start = start_step
         losses = []
         for step in range(start, self.tc.steps):
-            if step == self.tc.fail_at_step:
-                raise RuntimeError(f"injected failure at step {step}")
-            batch = {
-                k: jnp.asarray(v) for k, v in self.pipeline.batch_at(step).items()
-            }
-            t0 = time.perf_counter()
-            if "fault" in self.hooks:
-                # fault-injection point (launch/elastic.FaultInjector): sits
-                # after t0 so an injected straggler stall lands in the
-                # measured dt and trips the watchdog below
-                self.hooks["fault"](step)
-            state, metrics = self.step_fn(state, batch)
-            loss = float(jax.device_get(metrics["loss"]))
-            dt = time.perf_counter() - t0
-            obs_metrics.observe("train.step_ms", dt * 1e3)
-            tokens = getattr(self.pipeline, "local_batch", 0) * getattr(
-                self.pipeline.cfg, "seq_len", 0)
-            if tokens and dt > 0:
-                obs_metrics.observe("train.tokens_per_s", tokens / dt)
-            gc = self.tc.guard
-            if gc is not None and bool(jax.device_get(metrics["fault"])):
-                # the jitted step already skipped the update in-device; the
-                # host side decodes provenance, records the skip, and
-                # escalates to a rewind after K consecutive faults
-                from ..core.plan import NumericsFault, guard_faults
+            # one profiler step per training step (a no-op until a profiler
+            # session runs): its batch, dispatch, host sync and checkpoint
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if step == self.tc.fail_at_step:
+                    raise RuntimeError(f"injected failure at step {step}")
+                batch = {
+                    k: jnp.asarray(v) for k, v in self.pipeline.batch_at(step).items()
+                }
+                t0 = time.perf_counter()
+                if "fault" in self.hooks:
+                    # fault-injection point (launch/elastic.FaultInjector): sits
+                    # after t0 so an injected straggler stall lands in the
+                    # measured dt and trips the watchdog below
+                    self.hooks["fault"](step)
+                state, metrics = self.step_fn(state, batch)
+                loss = float(jax.device_get(metrics["loss"]))
+                dt = time.perf_counter() - t0
+                obs_metrics.observe("train.step_ms", dt * 1e3)
+                gc = self.tc.guard
+                if gc is not None and bool(jax.device_get(metrics["fault"])):
+                    # the jitted step already skipped the update in-device; the
+                    # host side decodes provenance, records the skip, and
+                    # escalates to a rewind after K consecutive faults
+                    from ..core.plan import NumericsFault, guard_faults
 
-                if self.guard_leaves is None:
-                    self.guard_leaves = guard_leaf_names(gc, state)
-                faults = guard_faults(
-                    gc, np.asarray(jax.device_get(metrics["guard"])),
-                    self.guard_leaves)
-                if not faults:  # norm-only trip (gnorm > max_grad_norm)
-                    faults = ({"leaf": "grad_norm", "kind": "norm",
-                               "value": float(jax.device_get(
-                                   metrics["grad_norm"]))},)
-                self.guard_counters["faults"] += 1
-                self._consecutive_faults += 1
-                obs_metrics.inc("train.guard.faults")
-                control_event(
-                    "numerics_fault", step=step,
-                    consecutive=self._consecutive_faults,
-                    leaves=[f["leaf"] for f in faults[:4]])
-                if "numerics_fault" in self.hooks:
-                    self.hooks["numerics_fault"](
-                        step, faults, self._consecutive_faults)
-                if self._consecutive_faults >= gc.rewind_after:
-                    raise NumericsFault(step, faults,
-                                        self._consecutive_faults)
-                self.guard_counters["skips"] += 1
-                self.skipped_steps.append(step)
-                obs_metrics.inc("train.guard.skips")
-                control_event("skip_step", step=step)
-                if "log" in self.hooks:
-                    self.hooks["log"](
-                        f"step {step} numerics fault -> skipped "
-                        f"({self._consecutive_faults} consecutive): "
-                        + ", ".join(f"{f['leaf']}[{f['kind']}]"
-                                    for f in faults[:4]))
+                    if self.guard_leaves is None:
+                        self.guard_leaves = guard_leaf_names(gc, state)
+                    faults = guard_faults(
+                        gc, np.asarray(jax.device_get(metrics["guard"])),
+                        self.guard_leaves)
+                    if not faults:  # norm-only trip (gnorm > max_grad_norm)
+                        faults = ({"leaf": "grad_norm", "kind": "norm",
+                                   "value": float(jax.device_get(
+                                       metrics["grad_norm"]))},)
+                    self.guard_counters["faults"] += 1
+                    self._consecutive_faults += 1
+                    obs_metrics.inc("train.guard.faults")
+                    control_event(
+                        "numerics_fault", step=step,
+                        consecutive=self._consecutive_faults,
+                        leaves=[f["leaf"] for f in faults[:4]])
+                    if "numerics_fault" in self.hooks:
+                        self.hooks["numerics_fault"](
+                            step, faults, self._consecutive_faults)
+                    if self._consecutive_faults >= gc.rewind_after:
+                        raise NumericsFault(step, faults,
+                                            self._consecutive_faults)
+                    self.guard_counters["skips"] += 1
+                    self.skipped_steps.append(step)
+                    obs_metrics.inc("train.guard.skips")
+                    control_event("skip_step", step=step)
+                    if "log" in self.hooks:
+                        self.hooks["log"](
+                            f"step {step} numerics fault -> skipped "
+                            f"({self._consecutive_faults} consecutive): "
+                            + ", ".join(f"{f['leaf']}[{f['kind']}]"
+                                        for f in faults[:4]))
+                    if self.tc.ckpt_dir and (step + 1) % self.tc.ckpt_every == 0:
+                        self._save(step + 1, state, step)
+                    continue
+                self._consecutive_faults = 0
+                self.step_times.append(dt)
+                losses.append(loss)
+                if "metrics" in self.hooks:
+                    self.hooks["metrics"](step, loss)
+                # straggler watchdog (real deployment: report to coordinator,
+                # trigger backup-worker promotion; here: hook + log)
+                if len(self.step_times) >= 8:
+                    med = float(np.median(self.step_times[-32:]))
+                    if dt > self.tc.straggler_factor * med:
+                        control_event("straggler", step=step, dt_ms=dt * 1e3,
+                                      median_ms=med * 1e3)
+                        if "straggler" in self.hooks:
+                            self.hooks["straggler"](step, dt, med)
                 if self.tc.ckpt_dir and (step + 1) % self.tc.ckpt_every == 0:
                     self._save(step + 1, state, step)
-                continue
-            self._consecutive_faults = 0
-            self.step_times.append(dt)
-            losses.append(loss)
-            if "metrics" in self.hooks:
-                self.hooks["metrics"](step, loss)
-            # straggler watchdog (real deployment: report to coordinator,
-            # trigger backup-worker promotion; here: hook + log)
-            if len(self.step_times) >= 8:
-                med = float(np.median(self.step_times[-32:]))
-                if dt > self.tc.straggler_factor * med:
-                    control_event("straggler", step=step, dt_ms=dt * 1e3,
-                                  median_ms=med * 1e3)
-                    if "straggler" in self.hooks:
-                        self.hooks["straggler"](step, dt, med)
-            if self.tc.ckpt_dir and (step + 1) % self.tc.ckpt_every == 0:
-                self._save(step + 1, state, step)
-            if "log" in self.hooks and step % self.tc.log_every == 0:
-                self.hooks["log"](f"step {step} loss {loss:.4f} ({dt*1e3:.0f} ms)")
+                if "log" in self.hooks and step % self.tc.log_every == 0:
+                    self.hooks["log"](f"step {step} loss {loss:.4f} ({dt*1e3:.0f} ms)")
         if self.tc.ckpt_dir:
             self._save(self.tc.steps, state, self.tc.steps - 1, prune=False)
         return state, losses

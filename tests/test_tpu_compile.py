@@ -62,3 +62,48 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
         chunk=128, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_partitioned_train_step_keeps_the_scopes_for_v5e(one_chip):
+    """The reduced qwen1.5-0.5b train step through ``spmd_partition``,
+    compiled for a described v5e: every matmul sits under one of the
+    model's four scopes, forward and backward (``transpose(...)``)."""
+    import re
+
+    import numpy as np
+
+    from repro.configs.base import get_strategy
+    from repro.configs.registry import get_config
+    from repro.core.partitioner import spmd_partition
+    from repro.core.sharding import Mesh
+    from repro.launch.train import reduced_config
+    from repro.train.loop import TrainConfig, init_state, make_train_step
+    from repro.train.optimizer import get_optimizer
+
+    (device,) = one_chip.device_set
+    jmesh = jax.sharding.Mesh(np.asarray([[device]]), ("data", "model"))
+    cfg = reduced_config(get_config("qwen1.5-0.5b"), 16).with_(
+        xent_chunk=0, attn_chunk=64)
+    st, opt, tc = (get_strategy("2d_finalized"),
+                   get_optimizer("adafactor", lr=0.01), TrainConfig())
+    state = jax.eval_shape(lambda: init_state(cfg, st, opt, tc,
+                                              jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((2, 128), jnp.int32)
+             for k in ("tokens", "labels")}
+    leaves, tdef = jax.tree_util.tree_flatten((state, batch))
+    step = make_train_step(cfg, st, opt, tc)
+    runner = spmd_partition(
+        lambda *xs: step(*jax.tree_util.tree_unflatten(tdef, xs))[0],
+        jmesh, Mesh.create((1, 1), ("data", "model")))
+    args = [_shape(one_chip, x.shape, x.dtype) for x in leaves]
+    jax.eval_shape(runner, *args)
+    (entry,) = runner.plans.values()
+    hlo = entry.call.lower(*args).compile().as_text()
+    names = re.findall(r'op_name="([^"]+)"', hlo)
+    dots = [n for n in names if n.endswith("dot_general")]
+    scoped = re.compile(r"\b(attention|mlp|head|optimizer)\b")
+    assert dots and all(scoped.search(n) for n in dots)
+    for scope in ("attention", "mlp", "head"):
+        assert any(scope in n and "transpose(" not in n for n in dots), scope
+        assert any(scope in n and "transpose(" in n for n in dots), scope
+    assert any(re.search(r"\boptimizer\b", n) for n in names)
