@@ -370,6 +370,7 @@ def registry_problem(arch: str, mesh: Mesh, batch: int = 8, seq: int = 32,
 
     from repro.configs.base import get_strategy
     from repro.configs.registry import default_strategy, get_config
+    from repro.core.compat import trace_for
     from repro.launch.train import reduced_config
     from repro.models import api as model_api
     from repro.models.layers import tree_shapes, tree_specs
@@ -392,9 +393,8 @@ def registry_problem(arch: str, mesh: Mesh, batch: int = 8, seq: int = 32,
         batch_in["frames"] = jax.ShapeDtypeStruct(
             (batch, max(seq // 2, 16), cfg.d_model), jnp.bfloat16
         )
-    closed = jax.make_jaxpr(
-        lambda p, b: model_api.loss_fn(cfg, st, p, b)
-    )(shapes, batch_in)
+    closed = trace_for(
+        mesh, lambda p, b: model_api.loss_fn(cfg, st, p, b), shapes, batch_in)
     # hand-annotated baseline: the Strategy's Table-1 specs on the same invars
     batch_specs = {k: P(("data",)) for k in batch_in}
     spec_leaves = jax.tree_util.tree_leaves(
@@ -431,6 +431,7 @@ def registry_pipeline_problem(arch: str, mesh: Mesh, decision,
 
     from repro.configs.base import get_strategy
     from repro.configs.registry import default_strategy, get_config
+    from repro.core.compat import trace_for
     from repro.launch.train import reduced_config
     from repro.models import api as model_api
     from repro.models.layers import is_param, tree_shapes, tree_specs
@@ -470,9 +471,9 @@ def registry_pipeline_problem(arch: str, mesh: Mesh, decision,
         "tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
         "labels": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
     }
-    closed = jax.make_jaxpr(
-        lambda p, b: pipelined_loss_fn(cfg, st, p, b, decision, mesh)
-    )(shapes, batch_in)
+    closed = trace_for(
+        mesh, lambda p, b: pipelined_loss_fn(cfg, st, p, b, decision, mesh),
+        shapes, batch_in)
     batch_specs = {k: P(("data",)) for k in batch_in}
     spec_leaves = jax.tree_util.tree_leaves(
         (tree_specs(tree), batch_specs),

@@ -21,6 +21,7 @@ from jax import core, lax
 from jax.extend import core as excore
 
 from .annotate import annotate_p
+from .compat import trace_for
 from .propagation import Propagation, propagate
 from .sharding import Mesh, Sharding, to_named_sharding
 
@@ -120,7 +121,8 @@ def gspmd_jit(fn, jmesh, mesh: Mesh, static_argnums=()):
         flat, treedef = jax.tree_util.tree_flatten(args)
         key = (treedef, tuple((x.shape, str(jnp.result_type(x))) for x in flat))
         if key not in cache:
-            closed = jax.make_jaxpr(fn)(*args)
+            closed, out_shape = trace_for(mesh, fn, *args, return_shape=True)
+            out_tree = jax.tree_util.tree_structure(out_shape)
             prop = propagate(closed, mesh)
 
             def constrained(*inner_args):
@@ -128,17 +130,12 @@ def gspmd_jit(fn, jmesh, mesh: Mesh, static_argnums=()):
                 outs = eval_with_constraints(
                     closed.jaxpr, closed.consts, prop, jmesh, *inner_flat
                 )
-                return jax.tree_util.tree_unflatten(
-                    jax.tree_util.tree_structure(
-                        jax.eval_shape(fn, *inner_args)
-                    ),
-                    list(outs),
-                )
+                return jax.tree_util.tree_unflatten(out_tree, list(outs))
 
             cache[key] = (jax.jit(constrained), prop)
         return cache[key][0](*args)
 
     wrapped.propagation_for = lambda *args: propagate(
-        jax.make_jaxpr(fn)(*args), mesh
+        trace_for(mesh, fn, *args), mesh
     )
     return wrapped

@@ -37,6 +37,8 @@ it.
 """
 from __future__ import annotations
 
+import contextvars
+
 import jax
 
 # kind -> (rtol, atol); see module docstring for the policy table
@@ -110,6 +112,31 @@ def get_abstract_mesh():
 def set_mesh(jmesh):
     """Context manager making ``jmesh`` the ambient mesh."""
     return jax.set_mesh(jmesh)
+
+
+# devices of the mesh that the program being traced is for (1: none)
+_TRACE_DEVICES = contextvars.ContextVar("trace_devices", default=1)
+
+
+def trace_for(mesh, fn, *args, return_shape: bool = False):
+    """``jax.make_jaxpr(fn)(*args)`` for a program that will run on ``mesh``.
+
+    Partitioners and the sharding search trace with global shapes and no
+    ambient mesh, so the program's own code cannot see how it will be split;
+    while it is traced here, :func:`partition_devices` gives the number of
+    devices of ``mesh``.  Every trace of a program for a mesh goes through
+    this function, so the program analysed is the one that runs."""
+    token = _TRACE_DEVICES.set(int(mesh.devices.size))
+    try:
+        return jax.make_jaxpr(fn, return_shape=return_shape)(*args)
+    finally:
+        _TRACE_DEVICES.reset(token)
+
+
+def partition_devices() -> int:
+    """Devices of the mesh the program is traced for by :func:`trace_for`
+    (1 outside it)."""
+    return _TRACE_DEVICES.get()
 
 
 def axis_size(name: str) -> int:

@@ -48,7 +48,7 @@ from jax.extend import core as excore
 from repro.obs.trace import span
 
 from .annotate import annotate_p
-from .compat import shard_map
+from .compat import shard_map, trace_for
 from .einsum_rules import partitioned_einsum
 from .propagation import Propagation, propagate
 from .reshard import reshard_local, shard_shape
@@ -644,7 +644,7 @@ def spmd_partition(fn, jmesh, mesh: Mesh, compile_plans: bool = True,
         t0 = time.perf_counter()
         phases: Dict[str, float] = {}
         with span("repro.partition.make_jaxpr", phases):
-            closed = jax.make_jaxpr(fn)(*args)
+            closed = trace_for(mesh, fn, *args)
         pkey: Optional[tuple] = None
         if process_cache:
             pkey = (

@@ -31,8 +31,13 @@ from .sharding import Mesh, Sharding, is_refinement, merge_shardings
 MaybeS = Optional[Sharding]
 
 
-def _subjaxpr(params):
-    """Find the sub-jaxpr in an equation's params, if any."""
+def _subjaxpr(eqn):
+    """Find the sub-jaxpr an equation calls, if any.  A Pallas kernel's
+    ``jaxpr`` is a program over blocks of its operands, not a function of
+    them: nothing propagates through it."""
+    if eqn.primitive.name == "pallas_call":
+        return None
+    params = eqn.params
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
         if key in params:
             j = params[key]
@@ -146,7 +151,7 @@ class Propagation:
             self.refine(eqn.outvars[0], self.get(eqn.invars[0]))
             self.refine(eqn.invars[0], self.get(eqn.outvars[0]))
             return
-        sub = _subjaxpr(eqn.params)
+        sub = _subjaxpr(eqn)
         if sub is not None:
             self._apply_call(eqn, sub)
             return
@@ -276,7 +281,7 @@ class Propagation:
     def _prio(eqn) -> int:
         if eqn.primitive is annotate_p:
             return 0
-        if _subjaxpr(eqn.params) is not None:
+        if _subjaxpr(eqn) is not None:
             return 2
         return PRIORITY.get(eqn.primitive.name, MAX_PRIORITY)
 

@@ -1,18 +1,24 @@
-"""Flash attention Pallas TPU kernel.
+"""Flash attention on the TPU, forward and backward.
 
-TPU-native adaptation of the memory-bound attention hot spot: blocked online
-softmax with the (batch·heads, q_blocks, kv_blocks) grid — the kv dim is the
-innermost (sequential) grid dim, so the m/l/acc accumulators live in VMEM
-scratch and the output block is revisited.  Causal block skipping avoids the
-2× masked-compute waste of the XLA chunked path.  GQA is native: the kv
-BlockSpec index_map maps q-head h to kv-head h // group_size, so kv blocks are
-never materialized per-q-head.
+A thin entry over the Pallas kernels JAX ships
+(``jax.experimental.pallas.ops.tpu.flash_attention``): a forward kernel and
+dK/dV and dQ kernels under one ``custom_vjp``, bf16 operands with f32
+accumulation on the MXU, and causal block skipping, so the (S, T) scores
+live in VMEM one block at a time and never reach HBM.  What this module adds
+is the choice of block sizes, made from the shape alone, and GQA: k and v
+are repeated to the q heads before the call, and the transpose of that
+repeat sums their gradients over each group in f32.  The kernel has
+rounded each head's dk and dv to bf16 by then, one rounding more than an
+f32 group sum inside the kernel would make.
 
-Block sizes default to (128, 128): MXU-aligned (128 lanes) and small enough
-that q,k,v,acc blocks fit VMEM comfortably:
-  q (128, D) + k,v (128, D) + scores (128,128) f32 + acc (128, D) f32
-  ≈ 0.25 MB for D=128 — far under the ~16 MB VMEM budget, leaving room for
-double buffering of the k/v streams.
+Block sizes.  Every sequence block is the largest multiple of 128 that
+divides the length and is at most ``SEQ_BLOCK``; 128 is the kernels'
+minimum.  ``SEQ_BLOCK`` comes from a sweep of the kernel alone on a TPU v5e
+(forward + backward at B 4, H 16, S 1,024, D 64 in bf16; the table is in
+PERF.md): the shipped default, 128 on every axis, took 2.9x as long as
+512-blocks and was slower than XLA's chunked loop; 1,024-blocks lose the
+causal skipping inside the sequence.  More than one batch row per forward
+grid step (``block_b``) gained nothing there, so it stays 1.
 """
 from __future__ import annotations
 
@@ -21,118 +27,77 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import flash_attention as _tpu_flash
 
-NEG_INF = -1e30
-
-
-def _attn_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, causal: bool, block_q: int, block_k: int, nk: int,
-):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
-
-    if causal:
-        # skip blocks entirely above the diagonal (saves ~2x compute)
-        @pl.when(qi * block_q + block_q - 1 >= kj * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
-
-    @pl.when(kj == nk - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+MIN_BLOCK = _tpu_flash.MIN_BLOCK_SIZE  # 128
+SEQ_BLOCK = 512
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "group_size", "interpret"),
-)
-def flash_attention(
-    q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int = 128,
-    group_size: int = 1, interpret: bool = False,
-):
-    """q: (B, Hq, S, D); k, v: (B, Hkv, T, D) with Hq = Hkv * group_size.
+def _seq_block(n: int) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most
+    ``SEQ_BLOCK``."""
+    if n % MIN_BLOCK:
+        raise ValueError(f"flash_attention: length {n} is not a multiple "
+                         f"of {MIN_BLOCK}")
+    return max(b for b in range(MIN_BLOCK, min(n, SEQ_BLOCK) + 1, MIN_BLOCK)
+               if n % b == 0)
 
-    Returns (B, Hq, S, D).  S % block_q == 0 and T % block_k == 0 required
-    (callers pad per §4.1).  ``interpret=True`` runs the Pallas interpreter
-    (the CPU path).
+
+def block_sizes(q_len: int, kv_len: int) -> _tpu_flash.BlockSizes:
+    """The kernels' blocks for this shape (forward, dK/dV and dQ)."""
+    bq, bk = _seq_block(q_len), _seq_block(kv_len)
+    return _tpu_flash.BlockSizes(
+        block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
+        block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
+        block_q_dkv=bq,
+        block_k_major_dq=bk, block_k_dq=bk, block_q_dq=bq,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "interpret",
+                                             "blocks"))
+def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = False,
+                    blocks: _tpu_flash.BlockSizes | None = None):
+    """q: (B, Hq, S, D); k, v: (B, Hkv, T, D) with Hq a multiple of Hkv.
+
+    Returns (B, Hq, S, D) in q's dtype; differentiable.  S and T are
+    multiples of 128; D is below 128 or a multiple of it.  ``interpret=True``
+    runs the kernels in the Pallas TPU interpreter (the CPU path);
+    ``blocks`` overrides :func:`block_sizes` (for sweeps and tests).
     """
-    B, Hq, S, D = q.shape
-    _, Hkv, T, _ = k.shape
-    assert Hq == Hkv * group_size, (Hq, Hkv, group_size)
-    assert S % block_q == 0 and T % block_k == 0, (S, T, block_q, block_k)
-    nq, nk = S // block_q, T // block_k
-    scale = 1.0 / math.sqrt(D)
-
-    grid = (B * Hq, nq, nk)
-
-    def q_map(bh, i, j):
-        return (bh // Hq, bh % Hq, i, 0)
-
-    def kv_map(bh, i, j):
-        return (bh // Hq, (bh % Hq) // group_size, j, 0)
-
-    kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, nk=nk,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda bh, i, j: q_map(bh, i, j)),
-            pl.BlockSpec((1, 1, block_k, D), lambda bh, i, j: kv_map(bh, i, j)),
-            pl.BlockSpec((1, 1, block_k, D), lambda bh, i, j: kv_map(bh, i, j)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda bh, i, j: (bh // Hq, bh % Hq, i, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pl_scratch((block_q,), jnp.float32),   # m: running max
-            pl_scratch((block_q,), jnp.float32),   # l: running denom
-            pl_scratch((block_q, D), jnp.float32), # acc: running numerator
-        ],
-        interpret=interpret,
-    )(
-        q.reshape(B, Hq, S, D),
-        k,
-        v,
-    )
+    _, Hq, S, D = q.shape
+    Hkv, T = k.shape[1], k.shape[2]
+    if Hq % Hkv:
+        raise ValueError(f"flash_attention: {Hq} q heads over {Hkv} kv heads")
+    if Hq != Hkv:
+        # repeated through f32, so that the gradient's sum over each
+        # group runs in f32
+        k, v = (jnp.repeat(x.astype(jnp.float32), Hq // Hkv, axis=1)
+                .astype(x.dtype) for x in (k, v))
+    call = functools.partial(
+        _tpu_flash.flash_attention, causal=causal, sm_scale=1.0 / math.sqrt(D),
+        block_sizes=blocks or block_sizes(S, T))
+    return (_interpreted(call) if interpret else call)(q, k, v)
 
 
-def pl_scratch(shape, dtype):
-    """A VMEM scratch buffer (the interpreter models VMEM too)."""
-    return pltpu.VMEM(shape, dtype)
+def _interpreted(fn):
+    """``fn`` run, and differentiated, in the Pallas TPU interpreter: the
+    backward kernels are traced when the gradient is, outside the forward's
+    call, so the interpreter is switched on in both rules."""
+
+    @jax.custom_vjp
+    def f(*args):
+        with pltpu.force_tpu_interpret_mode():
+            return fn(*args)
+
+    def fwd(*args):
+        with pltpu.force_tpu_interpret_mode():
+            return jax.vjp(fn, *args)
+
+    def bwd(vjp, g):
+        with pltpu.force_tpu_interpret_mode():
+            return vjp(g)
+
+    f.defvjp(fwd, bwd)
+    return f

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import pl_scratch
-
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, state_ref, *, Q: int):
     ci = pl.program_id(2)
@@ -108,7 +106,7 @@ def ssd_scan(x, dt, B, C, A, *, chunk: int = 128, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, 1, Q, hd), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct(xh.shape, x.dtype),
-        scratch_shapes=[pl_scratch((hd, ds), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
         interpret=interpret,
     )(xh, dth, B, C, A.astype(jnp.float32))
     return jnp.transpose(yh, (0, 2, 1, 3))

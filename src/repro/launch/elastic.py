@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.core.compat import trace_for
 from repro.core.plan import NumericsFault
 from repro.core.sharding import Mesh
 
@@ -363,9 +364,8 @@ def sharding_problem(cfg, st, mesh: Mesh, local_batch: int, seq_len: int):
         "tokens": jax.ShapeDtypeStruct((local_batch, seq_len), jnp.int32),
         "labels": jax.ShapeDtypeStruct((local_batch, seq_len), jnp.int32),
     }
-    closed = jax.make_jaxpr(
-        lambda p, b: api.loss_fn(cfg, st, p, b)
-    )(shapes, batch_in)
+    closed = trace_for(
+        mesh, lambda p, b: api.loss_fn(cfg, st, p, b), shapes, batch_in)
     spec_leaves = jax.tree_util.tree_leaves(
         (tree_specs(tree), {k: P(("data",)) for k in batch_in}),
         is_leaf=lambda x: x is None or isinstance(x, P),

@@ -12,10 +12,21 @@ production-friendly layout here:
   W_O columns, so their contribution is exactly zero — the §4.1 masking argument.
   The waste shows up honestly in the roofline MODEL_FLOPS/HLO_FLOPS ratio.
 
-Attention itself is kv-chunked with an online softmax ("flash-in-XLA") so the
-dry-run never materializes (S, T) score tensors.  The Pallas flash kernel
-(kernels/flash_attention.py) computes the same math, but no model calls it
-yet: every model runs this jnp path, on the chip too.
+Full-sequence self-attention (``self_attention``, which every model's
+training step calls) runs the Pallas flash kernel with its backward pass
+(``kernels/ops.py`` ``attention_model_layout``) when the code can see that it
+may: the backend is a TPU, the length is a multiple of 128, the head size is
+64 or a multiple of 128, each q head has its own kv head, and no mesh axis
+splits attention's batch, sequence or heads (an ambient mesh, by the
+strategy's rules; under a partitioner, a mesh of one device).  Each trace
+counts its path in the metrics registry: ``attention.flash_kernel`` or
+``attention.xla_chunked``.
+
+Every other path runs ``chunked_attention``: kv-chunked with an online
+softmax ("flash-in-XLA") so the dry-run never materializes (S, T) score
+tensors.  That is decode, prefill, cross-attention, GQA, the CPU backend and
+meshes that shard attention; a sharded mesh would lower the kernel through
+the partitioner's gather-everything fallback.
 """
 from __future__ import annotations
 
@@ -27,6 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig, Strategy
+from ..core.compat import get_abstract_mesh, partition_devices
+from ..kernels import ops as kernel_ops
+from ..obs import metrics
 from .layers import Params, pspec, rope
 
 NEG_INF = -1e9
@@ -198,10 +212,35 @@ def self_attention(
     *,
     causal=True,
 ):
-    """Full-sequence self-attention (training / prefill)."""
+    """Full-sequence self-attention (training / prefill): the flash kernel
+    where :func:`uses_flash_kernel`, else the chunked loop."""
     q, k, v = project_qkv(cfg, st, p, x, x, positions)
-    attn = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
+    if uses_flash_kernel(st, q):
+        metrics.inc("attention.flash_kernel")
+        attn = kernel_ops.attention_model_layout(q, k, v, causal=causal)
+    else:
+        metrics.inc("attention.xla_chunked")
+        attn = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
     return out_projection(cfg, st, p, attn)
+
+
+def uses_flash_kernel(st: Strategy, q) -> bool:
+    """Whether self-attention over q (B,S,KR,Gl,D) runs the flash kernel:
+    on a TPU, S a multiple of 128, D 64 or a multiple of 128, one q head per
+    kv head, and no mesh axis splitting attention's batch, sequence or heads.
+
+    GQA stays on the loop: the kernel sees k and v repeated to the q heads
+    and rounds each head's dk and dv to bf16 before the group is summed,
+    which leaves dv further from f32 than the loop's (tests/test_kernels.py).
+    """
+    _, S, _, Gl, D = q.shape
+    if (not kernel_ops.on_tpu() or S % 128 or Gl != 1
+            or not (D == 64 or D % 128 == 0)):
+        return False
+    mesh = get_abstract_mesh()
+    if mesh is not None and not mesh.empty:
+        return all(st.axis_size(n) == 1 for n in ("batch", "seq", "kv"))
+    return partition_devices() == 1
 
 
 # ---------------------------------------------------------------------------------

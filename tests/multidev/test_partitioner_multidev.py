@@ -162,3 +162,54 @@ def test_einsum_partition_property(spec, axes):
     x = rng.standard_normal([DIMS[c] for c in lhs]).astype(np.float32)
     y = rng.standard_normal([DIMS[c] for c in rhs]).astype(np.float32)
     assert_close(run(f, x, y), jnp.einsum(spec, x, y), "coarse")
+
+
+def test_self_attention_on_a_2x2_mesh_keeps_the_chunked_loop(monkeypatch):
+    """With the backend check patched to a TPU, a reduced-Qwen loss
+    partitioned for a 2x2 mesh holds no ``pallas_call`` (the partitioner
+    would gather the kernel's operands), counts the chunked path, and
+    matches the unpartitioned loss."""
+    from repro.configs.base import get_strategy
+    from repro.configs.registry import get_config
+    from repro.kernels import ops
+    from repro.launch.train import reduced_config
+    from repro.models import transformer
+    from repro.models.layers import tree_init
+    from repro.obs import metrics
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = reduced_config(get_config("qwen1.5-0.5b"), 16).with_(
+        d_model=128, xent_chunk=0, attn_chunk=128)  # 2 heads of 64
+    st = get_strategy("2d_finalized")
+    params = tree_init(transformer.param_tree(cfg, st), jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 128), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+
+    def loss(p, b):
+        return transformer.loss_fn(cfg, st, p, b)
+
+    jm, m = make_jax_mesh((2, 2), ("data", "model")), Mesh.create(
+        (2, 2), ("data", "model"))
+    before = metrics.snapshot(include_sources=False)["counters"]
+    leaves, tdef = jax.tree_util.tree_flatten((params, batch))
+    runner = spmd_partition(
+        lambda *xs: loss(*jax.tree_util.tree_unflatten(tdef, xs)), jm, m)
+    got = float(runner(*leaves))
+    after = metrics.snapshot(include_sources=False)["counters"]
+    (entry,) = runner.plans.values()
+
+    def prims(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from prims(sub)
+
+    assert "pallas_call" not in set(prims(entry.plan.jaxpr))
+    took = {k: after.get(k, 0) - before.get(k, 0) for k in
+            ("attention.flash_kernel", "attention.xla_chunked")}
+    assert took == {"attention.flash_kernel": 0, "attention.xla_chunked": 1}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: False)
+        want = float(jax.jit(loss)(params, batch))
+    assert abs(got - want) <= 1e-3 * abs(want)

@@ -34,20 +34,28 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.mark.parametrize("S", [1024, 4096])
 @pytest.mark.parametrize(
     "hq,hkv,d",
     [(16, 16, 64),    # qwen1.5-0.5b: 16 heads x 64
      (24, 8, 128)],   # phi4-mini-3.8b: GQA 24/8 heads x 128
     ids=["qwen1.5-0.5b", "phi4-mini-3.8b"],
 )
-def test_flash_attention_compiles_for_v5e(one_chip, hq, hkv, d):
-    S = 4096
-    q = _shape(one_chip, (1, hq, S, d))
-    kv = _shape(one_chip, (1, hkv, S, d))
-    compiled = flash_attention.lower(
-        q, kv, kv, causal=True, group_size=hq // hkv, interpret=False,
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_flash_attention_compiles_for_v5e(one_chip, hq, hkv, d, S):
+    """Forward and backward, with the blocks the rule picks for the shape
+    (4 x 1,024 or 1 x 4,096 tokens)."""
+    B = 4096 // S
+    q = _shape(one_chip, (B, hq, S, d))
+    kv = _shape(one_chip, (B, hkv, S, d))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(
+            jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    # the forward, the dK/dV and the dQ kernels
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
 
 
 def test_ssd_scan_compiles_for_v5e(one_chip):
@@ -107,3 +115,54 @@ def test_partitioned_train_step_keeps_the_scopes_for_v5e(one_chip):
         assert any(scope in n and "transpose(" not in n for n in dots), scope
         assert any(scope in n and "transpose(" in n for n in dots), scope
     assert any(re.search(r"\boptimizer\b", n) for n in names)
+
+
+def test_partitioned_train_step_runs_the_flash_kernel_for_v5e(
+        one_chip, monkeypatch):
+    """The same step at 2 heads of 64, traced as on the chip (the backend
+    check patched, since this process's backend is the CPU): the flash
+    kernel's custom calls sit under ``attention`` in the forward pass and
+    under ``transpose(...)``, and no f32 score tensor is left."""
+    import re
+
+    import numpy as np
+
+    from repro.configs.base import get_strategy
+    from repro.configs.registry import get_config
+    from repro.core.partitioner import spmd_partition
+    from repro.core.sharding import Mesh
+    from repro.kernels import ops
+    from repro.launch.train import reduced_config
+    from repro.train.loop import TrainConfig, init_state, make_train_step
+    from repro.train.optimizer import get_optimizer
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    (device,) = one_chip.device_set
+    jmesh = jax.sharding.Mesh(np.asarray([[device]]), ("data", "model"))
+    S = 256
+    cfg = reduced_config(get_config("qwen1.5-0.5b"), 16).with_(
+        d_model=128, xent_chunk=0, attn_chunk=S)
+    st, opt, tc = (get_strategy("2d_finalized"),
+                   get_optimizer("adafactor", lr=0.01), TrainConfig())
+    state = jax.eval_shape(lambda: init_state(cfg, st, opt, tc,
+                                              jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((2, S), jnp.int32)
+             for k in ("tokens", "labels")}
+    leaves, tdef = jax.tree_util.tree_flatten((state, batch))
+    step = make_train_step(cfg, st, opt, tc)
+    runner = spmd_partition(
+        lambda *xs: step(*jax.tree_util.tree_unflatten(tdef, xs))[0],
+        jmesh, Mesh.create((1, 1), ("data", "model")))
+    args = [_shape(one_chip, x.shape, x.dtype) for x in leaves]
+    jax.eval_shape(runner, *args)
+    (entry,) = runner.plans.values()
+    hlo = entry.call.lower(*args).compile().as_text()
+    kernels = [m.group(1) for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               for m in [re.search(r'op_name="([^"]+)"', line)] if m]
+    assert kernels and all(re.search(r"\battention\b", n) for n in kernels)
+    assert any("transpose(" not in n for n in kernels)
+    assert any("transpose(" in n for n in kernels)
+    # the chunked loop's [B, S, heads, 1, S] f32 scores are gone
+    assert f"f32[2,{S},2,1,{S}]" not in hlo
