@@ -39,6 +39,9 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp: str = "swiglu"  # swiglu | relu2 | gelu
     rope: bool = True
+    rope_fraction: float = 1.0  # share of the head that rotary covers
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6  # RMSNorm eps of every block and final norm
     causal: bool = True
     # MoE
     moe: bool = False
@@ -82,6 +85,12 @@ class ModelConfig:
     @property
     def dh(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def rotary_dims(self) -> int:
+        """Leading dims of each head that rotary rotates; the rest pass
+        through (Phi-3's ``partial_rotary_factor``)."""
+        return int(round(self.rope_fraction * self.dh))
 
     @property
     def expert_d_ff(self) -> int:

@@ -74,9 +74,9 @@ from .collective_planner import (
 )
 from .compat import eqn_name_stack, under_name_stack
 from .einsum_rules import compile_einsum, execute_einsum
-from .propagation import Propagation, PropagationResult
+from .propagation import Propagation, PropagationResult, _subjaxpr
 from .reshard import shard_shape
-from .rules import ELEMENTWISE
+from .rules import ELEMENTWISE, IndexDims, index_dims
 from .sharding import Mesh, Sharding, merge_shardings, replicated
 
 Env = Dict[object, object]
@@ -151,6 +151,11 @@ class PlanStep:
     # jvp/transpose transforms), which execute() binds the step under so the
     # partitioned program's op names keep them; None binds under the caller's
     name_stack: Optional[object] = None
+    # gather / scatter-add steps run per shard: the layout they index into
+    index: Optional["IndexShards"] = None
+    # fallback steps: (program, local shape, dtype bytes) of each operand
+    # reshard the fallback gathered, which ``PlanStats.fallback_bytes`` counts
+    gathered: Tuple[Tuple[ReshardProgram, Tuple[int, ...], int], ...] = ()
 
     @property
     def in_bytes(self) -> float:
@@ -239,6 +244,11 @@ class PlanStats:
     # primitive name -> equations lowered by the gather-op-reshard fallback
     # (inner jit/scan bodies included: they share their caller's stats)
     fallbacks: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # gather / scatter-add equations lowered to per-shard steps
+    sharded_gathers: int = 0
+    # bytes one device receives per execution from the fallback's operand
+    # gathers, as lowered (scan bodies at trip count)
+    fallback_bytes: float = 0.0
 
     def count(self, kind: str, n: int = 1) -> None:
         self.collectives[kind] = self.collectives.get(kind, 0) + n
@@ -274,6 +284,8 @@ class PlanStats:
             "steps": self.steps,
             "lattice": dict(self.lattice),
             "fallbacks": dict(self.fallbacks),
+            "sharded_gathers": self.sharded_gathers,
+            "fallback_bytes": self.fallback_bytes,
         }
 
 
@@ -611,7 +623,7 @@ def append_guard_steps(plan: PartitionPlan, guard: GuardConfig,
 # fallback analysis: which dims does a formatting op actually modify?
 # ---------------------------------------------------------------------------------
 #
-# §4.5: pad/slice/concatenate/rev only rewrite data along *some* dims; every
+# §4.5: pad/slice/split/concatenate/rev only rewrite data along *some* dims; every
 # other dim is elementwise, so its sharding can be kept.  The fallback then
 # gathers only the mesh axes on modified dims instead of fully replicating.
 
@@ -650,6 +662,9 @@ _FALLBACK_DIMS: Dict[str, Callable] = {
         dict(eqn.params),
     ),
     "slice": _slice_fallback,
+    "split": lambda eqn, shp: FallbackSpec(
+        (eqn.params["axis"],), dict(eqn.params)
+    ),
 }
 
 
@@ -706,8 +721,121 @@ def fallback_keep_sharding(eqn, in_shardings, mesh: Mesh) -> Optional[Tuple[Shar
 
 
 # ---------------------------------------------------------------------------------
+# gather / scatter-add per shard (GSPMD's partitioning of index ops)
+# ---------------------------------------------------------------------------------
+#
+# A gather reads whole rows of its operand at run-time indices; scatter-add,
+# its transpose, adds rows in.  Operand dims the indices do not address pass
+# through and operand batching dims pair with index batch dims, so both keep
+# their sharding.  A mesh axis that splits an *indexed* dim leaves each shard
+# a range of rows: the shard offsets the indices by the range's start and
+# masks those outside it, so a gather yields zeros there and a trailing psum
+# over the axis adds the one shard that held each row; scatter-add drops the
+# masked updates and exchanges nothing.  Updates split over an axis the
+# operand does not use leave partial sums, which the trailing psum adds.
+
+
+@dataclasses.dataclass
+class IndexShards:
+    """How one gather / scatter-add step finds its rows per shard; its run
+    reads it while traced, and ``plan_verify`` re-simulates it."""
+
+    operand_shape: Tuple[int, ...]  # global
+    operand_dims: Tuple[Tuple[str, ...], ...]  # the operand's dims_mapping
+    indexed: Tuple[int, ...]  # operand dim of each index-vector component
+    strides: Tuple[int, ...]  # per component: shard i holds rows from i*stride
+    partial_axes: Tuple[str, ...]  # the step leaves partial sums over these
+
+
+def _shard_index(mesh: Mesh, axes: Tuple[str, ...]):
+    """Position of this device's shard along a dim split over ``axes``
+    (the first axis major, as ``execute_program`` lays stacked axes)."""
+    i = 0
+    for a in axes:
+        i = i * mesh.axis_size(a) + lax.axis_index(a)
+    return i
+
+
+def _localize(idx, spec: IndexShards, mesh: Mesh, clip: bool):
+    """Indices into this shard's rows, the mask of those it holds (None
+    where no indexed dim is split) and, for ``clip=False``, the mask of
+    those in bounds of the whole dim.  Components on a split dim are
+    clamped to the whole dim first where ``clip``; the others keep their
+    global index, which the local op's mode treats as the unsplit op's."""
+    from jax import numpy as jnp
+
+    cols, held, inside = [], None, None
+
+    def both(a, b):
+        return b if a is None else a & b
+
+    for k, d in enumerate(spec.indexed):
+        c = idx[..., k]
+        axes = spec.operand_dims[d]
+        if axes and clip:
+            c = jnp.clip(c, 0, spec.operand_shape[d] - 1)
+        elif axes:
+            inside = both(inside, (c >= 0) & (c < spec.operand_shape[d]))
+        if axes:
+            c = c - _shard_index(mesh, axes) * spec.strides[k]
+            held = both(held, (c >= 0) & (c < spec.strides[k]))
+            c = jnp.clip(c, 0, spec.strides[k] - 1)
+        cols.append(c)
+    return jnp.stack(cols, -1).astype(idx.dtype), held, inside
+
+
+def _gather_run(spec: IndexShards, mesh: Mesh, prim, bind_params, rest,
+                fill):
+    """Local gather of the rows this shard holds, zeros elsewhere; where a
+    global index is out of bounds the first shard gives ``fill``."""
+    from jax import numpy as jnp
+
+    clip = bind_params["mode"] != lax.GatherScatterMode.FILL_OR_DROP
+
+    def run(env, reads, writes):
+        op, idx = _read(env, reads[0]), _read(env, reads[1])
+        idx, held, inside = _localize(idx, spec, mesh, clip)
+        out = prim.bind(op, idx, **bind_params)
+        if held is not None:
+            out = jnp.where(lax.broadcast_in_dim(held, out.shape, rest), out,
+                            jnp.zeros((), out.dtype))
+        if inside is not None:
+            axes = [a for d in spec.indexed for a in spec.operand_dims[d]]
+            first = sum(lax.axis_index(a) for a in axes) == 0
+            out = jnp.where(lax.broadcast_in_dim(inside, out.shape, rest),
+                            out, jnp.where(first, fill, 0).astype(out.dtype))
+        _write(env, writes[0], out)
+
+    return run
+
+
+def _scatter_run(spec: IndexShards, mesh: Mesh, prim, subfuns, bind_params,
+                 rest):
+    """Local scatter-add of the updates whose rows this shard holds."""
+    from jax import numpy as jnp
+
+    clip = bind_params["mode"] == lax.GatherScatterMode.CLIP
+
+    def run(env, reads, writes):
+        op, idx, upd = (_read(env, r) for r in reads)
+        idx, held, _ = _localize(idx, spec, mesh, clip)
+        if held is not None:
+            upd = jnp.where(lax.broadcast_in_dim(held, upd.shape, rest), upd,
+                            jnp.zeros((), upd.dtype))
+        _write(env, writes[0], prim.bind(*subfuns, op, idx, upd,
+                                         **bind_params))
+
+    return run
+
+
+# ---------------------------------------------------------------------------------
 # the builder: abstract interpretation over shardings, emitting steps
 # ---------------------------------------------------------------------------------
+
+
+# calls lowered to inner plans (``PlanBuilder._jit``) whose XLA lowering
+# runs the body under a scope of its own
+CALL_SCOPES = {"closed_call": "closed_call", "remat2": "checkpoint"}
 
 
 class PlanBuilder:
@@ -732,6 +860,7 @@ class PlanBuilder:
         stats: Optional[PlanStats] = None,
         optimize: bool = True,
         cost_only: bool = False,
+        trips: int = 1,
     ):
         self.jaxpr = jaxpr
         self.consts = tuple(consts)
@@ -742,6 +871,7 @@ class PlanBuilder:
         self.stats = stats if stats is not None else PlanStats()
         self.optimize = optimize
         self.cost_only = cost_only
+        self.trips = trips  # executions of this body per plan execution
 
     # -- sharding/shape bookkeeping ---------------------------------------------
     def sharding_of(self, v) -> Sharding:
@@ -850,15 +980,20 @@ class PlanBuilder:
         of duplicates is deliberately left to the optimizer pass so the
         benchmark can report what it saved.
         """
+        return self._reshard(v, tgt)[0]
+
+    def _reshard(self, v, tgt: Sharding):
+        """:meth:`reshard_operand`, also returning the program (None when
+        nothing moves) with the local shape and dtype bytes it ran on."""
         cur = self.sharding_of(v)
         if cur.dims_mapping == tgt.dims_mapping:
-            return v
+            return v, None
         lshape, dbytes = self._lshape(v), self._dbytes(v)
         prog = plan_reshard(cur, tgt, lshape, dbytes)
         self._account(prog, lshape, dbytes)
         proxy = ProxyVar(f"reshard:{cur}->{tgt}")
         self.emit_reshard(v, proxy, prog, lshape, dbytes, self._dtype(v))
-        return proxy
+        return proxy, (prog, lshape, dbytes)
 
     def _emit_program(self, src_key, out_key, prog: Optional[ReshardProgram],
                       lshape, dbytes, dtype) -> object:
@@ -885,6 +1020,12 @@ class PlanBuilder:
             ns = eqn_name_stack(eqn)
             for step in self.steps[first:]:
                 step.name_stack = ns
+            scope = CALL_SCOPES.get(eqn.primitive.name)
+            if scope and len(self.steps) > first:
+                # the call step, emitted last, runs its body under the scope
+                # XLA lowering gives it, as plain jit's op names have it
+                self.steps[-1].name_stack = eqn.source_info.name_stack.extend(
+                    scope)
         # output epilogue: reshards to the propagated output shardings are
         # first-class steps writing proxy keys, so CSE/DCE/fusion price them
         out_shardings: List[Sharding] = []
@@ -932,7 +1073,7 @@ class PlanBuilder:
             self._reshape(eqn)
         elif name == "conv_general_dilated":
             self._conv(eqn)
-        elif name == "jit":
+        elif name in ("jit", "closed_call", "remat2"):
             self._jit(idx, eqn)
         elif name == "scan":
             self._scan(idx, eqn)
@@ -940,6 +1081,8 @@ class PlanBuilder:
             self._stage_shift(eqn)
         elif name == "iota":
             self._iota(eqn)
+        elif name in ("gather", "scatter-add") and index_dims(eqn) is not None:
+            self._index_op(eqn, index_dims(eqn))
         else:
             self._fallback(eqn)
 
@@ -1362,6 +1505,89 @@ class PlanBuilder:
             flops=float(np.prod(lshape or (1,))), wbytes=(out_bytes,),
         ))
 
+    def _index_op(self, eqn, dims: IndexDims) -> None:
+        """A gather or scatter-add per shard (see :class:`IndexShards`).
+
+        The operand keeps its layout (a scatter's is its output's, as
+        propagated); batching dims take the operand's axes, else the
+        indices'; window dims the operand's; every other index dim keeps
+        the axes it has (a scatter's updates', else the indices'), less
+        those the operand or an earlier dim already uses.  The indices and
+        the updates are resharded to that."""
+        scatter = eqn.primitive.name == "scatter-add"
+        opv, iv = eqn.invars[0], eqn.invars[1]
+        ov = eqn.outvars[0]
+        rv = eqn.invars[2] if scatter else ov  # the updates / the output
+        op_sh = self.sharding_of(opv)
+        if scatter:
+            op_sh = self.prop.get(ov) or op_sh
+        op_dm = list(op_sh.dims_mapping)
+        idx_cur = self.sharding_of(iv).dims_mapping
+        have = self.sharding_of(rv).dims_mapping if scatter else None
+        idx_dm = [()] * len(idx_cur)
+        res_dm = [()] * len(self._gshape(rv))
+        used = set(op_sh.sharded_axes)
+
+        def free(axes):
+            kept = tuple(a for a in axes if a not in used)
+            used.update(kept)
+            return kept
+
+        for d, i, j in dims.batching:
+            if not op_dm[d] and not scatter:
+                op_dm[d] = free(idx_cur[i])
+            idx_dm[i] = res_dm[j] = op_dm[d]
+        for d, j in dims.window:
+            res_dm[j] = op_dm[d]
+        for i, j in dims.batch:
+            idx_dm[i] = res_dm[j] = free(have[j] if scatter else idx_cur[i])
+        op_sh = Sharding(self.mesh, tuple(op_dm))
+        masked = tuple(a for d in dims.indexed for a in op_dm[d])
+        partial = (tuple(a for i, _ in dims.batch for a in idx_dm[i])
+                   if scatter else masked)
+        gshape = self._gshape(opv)
+        spec = IndexShards(
+            operand_shape=gshape, operand_dims=tuple(op_dm),
+            indexed=dims.indexed,
+            strides=tuple(gshape[d] // op_sh.num_shards(d)
+                          for d in dims.indexed),
+            partial_axes=partial,
+        )
+        res_sh = Sharding(self.mesh, tuple(res_dm))
+        keys = [self.reshard_operand(opv, op_sh),
+                self.reshard_operand(iv, Sharding(self.mesh, tuple(idx_dm)))]
+        if scatter:
+            keys.append(self.reshard_operand(rv, res_sh))
+        out_sh = op_sh if scatter else res_sh
+        self.set_sharding(ov, out_sh)
+        lshape = shard_shape(tuple(ov.aval.shape), out_sh)
+        rest = tuple(sorted(j for _, j in dims.rows))
+        prim = eqn.primitive
+        subfuns, bind_params = prim.get_bind_params(eqn.params)
+        if scatter:
+            run = _scatter_run(spec, self.mesh, prim, subfuns, bind_params,
+                               rest)
+            flops = float(np.prod(shard_shape(self._gshape(rv), res_sh)
+                                  or (1,)))
+        else:
+            local = shard_shape(gshape, op_sh)
+            sizes = list(bind_params["slice_sizes"])
+            for d, _ in dims.window:
+                sizes[d] = local[d]
+            bind_params = dict(bind_params, slice_sizes=tuple(sizes))
+            run = _gather_run(spec, self.mesh, prim, bind_params, rest,
+                              eqn.params.get("fill_value"))
+            flops = float(np.prod(lshape or (1,)))
+        self.stats.sharded_gathers += 1
+        mid = ProxyVar(f"{prim.name}.partial") if partial else ov
+        db, dt = self._dbytes(ov), self._dtype(ov)
+        self.emit(PlanStep("compute", tuple(keys), (mid,), run, op=prim.name,
+                           flops=flops, wbytes=(_nbytes_of(lshape, db),),
+                           index=spec))
+        if partial:
+            self.stats.count("all-reduce", len(partial))
+            self.emit_collective(mid, ov, partial, "add", lshape, db, dt)
+
     def _iota(self, eqn) -> None:
         prim, params, ov = eqn.primitive, eqn.params, eqn.outvars[0]
         self.set_sharding(ov, replicated(self.mesh, len(params["shape"])))
@@ -1388,7 +1614,10 @@ class PlanBuilder:
         return optimize_plan(plan)
 
     def _jit(self, idx: int, eqn) -> None:
-        sub = eqn.params["jaxpr"]
+        """A call whose value is its body's: ``jit``, ``closed_call`` and
+        ``remat2`` (a differentiated checkpoint, whose saved residuals the
+        jaxpr already fixes), lowered to an inner plan over local shards."""
+        sub = _subjaxpr(eqn)
         inner_res = self._inner_result(idx, sub)
         # seed inner input shardings from ours where propagation left them open
         env = dict(inner_res.env)
@@ -1404,6 +1633,7 @@ class PlanBuilder:
         builder = PlanBuilder(
             sub.jaxpr, sub.consts, inner_res, self.mesh, stats=self.stats,
             optimize=self.optimize, cost_only=self.cost_only,
+            trips=self.trips,
         )
         inner_plan = self._optimize_inner(builder.build())
         for ov, osh in zip(eqn.outvars, inner_plan.out_shardings):
@@ -1458,6 +1688,7 @@ class PlanBuilder:
         builder = PlanBuilder(
             body, closed.consts, inner_res, self.mesh, stats=self.stats,
             optimize=self.optimize, cost_only=self.cost_only,
+            trips=self.trips * (p.get("length") or 1),
         )
         inner_plan = self._optimize_inner(builder.build())
         # carry consistency: carry-out must leave the body in the carry-in
@@ -1531,13 +1762,22 @@ class PlanBuilder:
         prim = eqn.primitive
         self.stats.fallbacks[prim.name] = self.stats.fallbacks.get(prim.name, 0) + 1
         invars, outvars = list(eqn.invars), list(eqn.outvars)
+        gathered = []
+
+        def gather(v, tgt):
+            key, moved = self._reshard(v, tgt)
+            if moved is not None:
+                gathered.append(moved)
+                self.stats.fallback_bytes += self.trips * moved[0].cost_bytes
+            return key
+
         if keep is not None:
             kept_sh, params = keep
             rank = kept_sh.rank
             keys = tuple(
-                self.reshard_operand(v, kept_sh)
+                gather(v, kept_sh)
                 if len(self._gshape(v)) == rank
-                else self.reshard_operand(v, replicated(self.mesh, len(self._gshape(v))))
+                else gather(v, replicated(self.mesh, len(self._gshape(v))))
                 for v in invars
             )
             subfuns, bind_params = prim.get_bind_params(params)
@@ -1583,13 +1823,14 @@ class PlanBuilder:
                     if ov in self.sh else 1.0
                     for ov in outvars if hasattr(ov, "aval")
                 )),
+                gathered=tuple(gathered),
             ))
             for mid, ov, prog, lshape, db, dt in post:
                 self.emit_reshard(mid, ov, prog, lshape, db, dt)
             return
         # unknown op: full gather, global op, re-slice to the propagated sharding
         keys = tuple(
-            self.reshard_operand(v, replicated(self.mesh, len(self._gshape(v))))
+            gather(v, replicated(self.mesh, len(self._gshape(v))))
             for v in invars
         )
         subfuns, bind_params = prim.get_bind_params(eqn.params)
@@ -1627,6 +1868,7 @@ class PlanBuilder:
                 np.prod(tuple(ov.aval.shape) or (1,))
                 for ov in outvars if hasattr(ov, "aval")
             )),
+            gathered=tuple(gathered),
         ))
         for mid, ov, prog, lshape, db, dt in post:
             self.emit_reshard(mid, ov, prog, lshape, db, dt)

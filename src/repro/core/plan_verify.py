@@ -29,6 +29,17 @@ compile (it is the default in ``compile_plan`` / ``spmd_partition`` /
   * collective axes must exist in the mesh; ppermute ``perm``s must be
     (partial) permutations — unique sources, unique destinations, in range.
 
+**Index ops** (gather / scatter-add run per shard, ``plan.IndexShards``)
+  * each indexed dim's stride is its global size over its shards, and the
+    rows at every shard boundary are re-simulated through the step's
+    offsets: each must land on exactly the shard the layout puts it on;
+  * a gather's partial axes are the axes of its indexed dims, a scatter's
+    none of the operand's, and the step's result goes next to a psum over
+    exactly those axes;
+  * ``PlanStats.sharded_gathers`` counts the index steps, and
+    ``PlanStats.fallback_bytes`` equals the fallback steps' operand gathers
+    re-simulated (scan bodies at trip count).
+
 **Schedule / cost sanity**
   * ``flops`` / ``wbytes`` / ``transient_bytes`` / ``dbytes`` non-negative;
   * planned-collective counts in ``plan.stats`` non-negative (fusion
@@ -154,6 +165,77 @@ def _wire_bytes_acct(plan) -> float:
             total += getattr(s, "_wire_bytes", 0.0)
         if s.inner is not None:
             total += s.call.get("trips", 1) * _wire_bytes_acct(s.inner)
+    return total
+
+
+def _check_index_step(plan, i: int, step, known_sh, out: List[str],
+                      where: str) -> None:
+    """Re-simulate where a per-shard gather / scatter-add finds its rows."""
+    spec = step.index
+    mesh = plan.mesh
+    names = set(mesh.axis_names)
+    masked = []
+    for k, d in enumerate(spec.indexed):
+        axes = spec.operand_dims[d]
+        if any(a not in names for a in axes):
+            out.append(f"{where}: indexed dim {d} on axes {axes} not in mesh "
+                       f"{mesh.axis_names}")
+            return
+        masked += axes
+        n = 1
+        for a in axes:
+            n *= mesh.axis_size(a)
+        g, stride = spec.operand_shape[d], spec.strides[k]
+        if g % n or stride != g // n:
+            out.append(f"{where}: indexed dim {d} of {g} rows over {n} "
+                       f"shard(s) holds {g // n} per shard, the step offsets "
+                       f"by {stride}")
+        rows = {0, g - 1}
+        for j in range(1, n):
+            rows.update((j * (g // n) - 1, j * (g // n)))
+        for c in sorted(r for r in rows if 0 <= r < g):
+            held = [s for s in range(n) if 0 <= c - s * stride < stride]
+            if held != [c // (g // n)]:
+                out.append(f"{where}: row {c} of dim {d} is taken from "
+                           f"shard(s) {held}; the layout holds it on shard "
+                           f"{c // (g // n)}")
+    src = known_sh.get(id(step.reads[0])) if step.reads else None
+    if src is not None and src != spec.operand_dims:
+        out.append(f"{where}: operand layout {src} disagrees with the step's "
+                   f"{spec.operand_dims}")
+    partial = spec.partial_axes
+    used = {a for axes in spec.operand_dims for a in axes}
+    if step.op == "gather" and tuple(partial) != tuple(masked):
+        out.append(f"{where}: gather leaves partial sums over {partial}, its "
+                   f"indexed dims are split over {tuple(masked)}")
+    if step.op == "scatter-add" and used & set(partial):
+        out.append(f"{where}: scatter-add's partial axes {partial} split its "
+                   "operand")
+    if partial:
+        w = id(step.writes[0])
+        nxt = next((s for s in plan.steps[i + 1:]
+                    if any(id(r) == w for r in s.reads)), None)
+        if (nxt is None or nxt.kind not in ("collective", "fused")
+                or nxt.reduce_op != "add" or set(nxt.axes) != set(partial)):
+            out.append(f"{where}: its partial sums over {partial} are not "
+                       "read by a psum over those axes")
+
+
+def _index_steps(plan) -> int:
+    return sum((s.index is not None)
+               + (_index_steps(s.inner) if s.inner is not None else 0)
+               for s in plan.steps)
+
+
+def _fallback_bytes(plan, trips: int = 1) -> float:
+    """The fallback steps' operand gathers, re-simulated, per execution."""
+    total = 0.0
+    for s in plan.steps:
+        for prog, lshape, dbytes in s.gathered:
+            total += trips * simulate(prog.src, prog.dst, list(prog.steps),
+                                      lshape, dbytes)
+        if s.inner is not None:
+            total += _fallback_bytes(s.inner, trips * s.call.get("trips", 1))
     return total
 
 
@@ -299,6 +381,8 @@ def _verify_body(plan, report: VerifyReport, path: str) -> None:
             k = known_sh.get(id(step.reads[0]))
             if k is not None:
                 known_sh[id(step.writes[0])] = k
+        if step.index is not None:
+            _check_index_step(plan, i, step, known_sh, out, where)
         # -- inner plans ------------------------------------------------------
         if step.inner is not None:
             trips = step.call.get("trips", 1)
@@ -342,6 +426,18 @@ def verify_plan(plan, strict: bool = True) -> VerifyReport:
         if n < 0:
             out.append(f"stats: negative planned-collective count "
                        f"{kind}={n} (double removal in an optimizer pass)")
+    n_index = _index_steps(plan)
+    if n_index != plan.stats.sharded_gathers:
+        out.append(f"stats: sharded_gathers {plan.stats.sharded_gathers} != "
+                   f"{n_index} index steps in the plan")
+    try:
+        fb = _fallback_bytes(plan)
+    except PlanError as e:
+        out.append(f"stats: a fallback's gather does not replay ({e})")
+    else:
+        if not _close(fb, plan.stats.fallback_bytes):
+            out.append(f"stats: fallback_bytes {plan.stats.fallback_bytes:.1f}"
+                       f" != {fb:.1f} re-simulated from the fallback steps")
     _accounting_checks(plan, out, "")
     _TELEMETRY["plans_verified"] += 1
     if report.violations:

@@ -9,11 +9,12 @@ Priorities (lower = propagates earlier), following the paper:
   0  elementwise ops and annotations (no comm if consistent; most intuitive)
   0  broadcast backward  /  1 broadcast forward (prefer deciding the small shape)
   1  transpose, reshape, pad/slice/concat and other data-formatting ops
-  2  dot_general, conv, reduce (dimension-changing)
+  2  dot_general, conv, reduce, gather, scatter-add (dimension-changing)
   3  everything else (no rule -> no propagation)
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -71,7 +72,7 @@ ELEMENTWISE = {
     "clamp", "nextafter", "copy", "real", "imag", "exp2", "tan", "asin",
     "acos", "atan", "sinh", "cosh", "asinh", "acosh", "atanh",
     "population_count", "clz", "reduce_precision", "gspmd_annotate",
-    "optimization_barrier",
+    "optimization_barrier", "add_any",
 }
 
 
@@ -349,6 +350,141 @@ def rule_stage_shift(eqn, in_sh, out_sh, direction):
 
 
 # ---------------------------------------------------------------------------------
+# gather / scatter-add — row lookups (the partitioner's rule: core/plan.py)
+# ---------------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexDims:
+    """The dims of one gather or scatter-add, by role.  ``result`` is the
+    gather's output or the scatter's updates; every index dim but the last
+    (the index vector) is a batching or a batch dim."""
+
+    indexed: Tuple[int, ...]  # operand dim of each index-vector component
+    batching: Tuple[Tuple[int, int, int], ...]  # (operand, indices, result)
+    window: Tuple[Tuple[int, int], ...]  # (operand, result): passes through
+    batch: Tuple[Tuple[int, int], ...]  # (indices, result)
+
+    @property
+    def rows(self) -> Tuple[Tuple[int, int], ...]:
+        """(indices, result) of every index dim but the index vector."""
+        return self.batch + tuple((i, j) for _, i, j in self.batching)
+
+
+def index_dims(eqn) -> Optional[IndexDims]:
+    """Roles of a gather's or scatter-add's dims, or None where it is not a
+    lookup of whole rows (an indexed dim kept in the window, a window
+    narrower than its operand dim, ``ONE_HOT`` mode): that keeps the
+    fallback."""
+    modes = (lax.GatherScatterMode.CLIP, lax.GatherScatterMode.FILL_OR_DROP,
+             lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    p = eqn.params
+    if p["mode"] not in modes:
+        return None
+    dn = p["dimension_numbers"]
+    operand, indices = eqn.invars[0].aval, eqn.invars[1].aval
+    if eqn.primitive.name == "gather":
+        result = eqn.outvars[0].aval
+        win, collapsed, index_map = (dn.offset_dims, dn.collapsed_slice_dims,
+                                     dn.start_index_map)
+        op_batch, idx_batch = (dn.operand_batching_dims,
+                               dn.start_indices_batching_dims)
+    else:
+        result = eqn.invars[2].aval
+        win, collapsed, index_map = (dn.update_window_dims,
+                                     dn.inserted_window_dims,
+                                     dn.scatter_dims_to_operand_dims)
+        op_batch, idx_batch = (dn.operand_batching_dims,
+                               dn.scatter_indices_batching_dims)
+    if (sorted(collapsed) != sorted(index_map)
+            or indices.shape[-1] != len(index_map)):
+        return None
+    win_ops = [d for d in range(operand.ndim)
+               if d not in collapsed and d not in op_batch]
+    rest = [j for j in range(result.ndim) if j not in win]
+    if (len(win_ops) != len(win) or len(rest) != indices.ndim - 1
+            or any(result.shape[j] != operand.shape[d]
+                   for d, j in zip(win_ops, win))):
+        return None
+    return IndexDims(
+        indexed=tuple(index_map),
+        batching=tuple((d, i, rest[i]) for d, i in zip(op_batch, idx_batch)),
+        window=tuple(zip(win_ops, win)),
+        batch=tuple((i, rest[i]) for i in range(indices.ndim - 1)
+                    if i not in idx_batch),
+    )
+
+
+def rule_gather(eqn, in_sh, out_sh, direction):
+    """Window dims follow the operand's, batching and batch dims the
+    indices' (batching dims the operand's too); the indexed dim has no
+    output dim.  Nothing flows back into the operand: the lookup adapts to
+    its table's layout, which the table's other users decide."""
+    dims = index_dims(eqn)
+    if dims is None:
+        return in_sh, out_sh
+    (s_op, s_idx), (s_out,) = in_sh[:2], out_sh
+    rank = eqn.outvars[0].aval.ndim
+    props = []
+    if s_op is not None:
+        dm = [None] * rank
+        for d, j in dims.window:
+            dm[j] = d
+        for d, _, j in dims.batching:
+            dm[j] = d
+        props.append(_project(s_op, dm, rank))
+    if s_idx is not None:
+        dm = [None] * rank
+        for i, j in dims.rows:
+            dm[j] = i
+        props.append(_project(s_idx, dm, rank))
+    new_out = _merge_many([s_out] + props)
+    new_idx = s_idx
+    if s_out is not None:
+        irank = eqn.invars[1].aval.ndim
+        dm = [None] * irank
+        for i, j in dims.rows:
+            dm[i] = j
+        new_idx = _merge_many([s_idx, _project(s_out, dm, irank)])
+    return [s_op, new_idx] + list(in_sh[2:]), [new_out]
+
+
+def rule_scatter_add(eqn, in_sh, out_sh, direction):
+    """The operand and the output share one layout; the updates' window and
+    batching dims take it, and the updates' batch dims and the indices
+    share theirs.  Nothing flows from the updates into the operand."""
+    dims = index_dims(eqn)
+    if dims is None:
+        return in_sh, out_sh
+    (s_op, s_idx, s_upd), (s_out,) = in_sh[:3], out_sh
+    table = _merge_many([s_op, s_out])
+    irank = eqn.invars[1].aval.ndim
+    urank = eqn.invars[2].aval.ndim
+    to_upd = [None] * urank  # update dim -> indices dim
+    to_idx = [None] * irank  # indices dim -> update dim
+    for i, j in dims.rows:
+        to_upd[j], to_idx[i] = i, j
+    upd_props, idx_props = [s_upd], [s_idx]
+    if table is not None:
+        dm = [None] * urank
+        for d, j in dims.window:
+            dm[j] = d
+        for d, _, j in dims.batching:
+            dm[j] = d
+        upd_props.append(_project(table, dm, urank))
+        dm = [None] * irank
+        for d, i, _ in dims.batching:
+            dm[i] = d
+        idx_props.append(_project(table, dm, irank))
+    if s_idx is not None:
+        upd_props.append(_project(s_idx, to_upd, urank))
+    if s_upd is not None:
+        idx_props.append(_project(s_upd, to_idx, irank))
+    return ([table, _merge_many(idx_props), _merge_many(upd_props)]
+            + list(in_sh[3:]), [table])
+
+
+# ---------------------------------------------------------------------------------
 # registry + priorities
 # ---------------------------------------------------------------------------------
 
@@ -390,5 +526,9 @@ RULES["dot_general"] = rule_dot_general
 PRIORITY["dot_general"] = 2
 RULES["conv_general_dilated"] = rule_conv
 PRIORITY["conv_general_dilated"] = 2
+RULES["gather"] = rule_gather
+PRIORITY["gather"] = 2
+RULES["scatter-add"] = rule_scatter_add
+PRIORITY["scatter-add"] = 2
 
 MAX_PRIORITY = 3

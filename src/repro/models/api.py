@@ -84,7 +84,7 @@ def pipeline_boundary(cfg: ModelConfig, st: Strategy) -> Optional[PipelineBounda
 
     @jax.named_scope("head")
     def epilogue(params, x, batch):
-        x = rms_norm(x, params["final_ln"])
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
         if cfg.xent_chunk:
             return streamed_xent(
                 cfg, st, x, params["embed"]["embedding"], batch["labels"]
@@ -106,7 +106,7 @@ def pipeline_boundary(cfg: ModelConfig, st: Strategy) -> Optional[PipelineBounda
         from .ssm import ssm_forward
 
         def layer(lp, x, _extra):
-            h = rms_norm(x, lp["ln"])
+            h = rms_norm(x, lp["ln"], cfg.norm_eps)
             return st.constrain(
                 x + ssm_forward(cfg, st, lp["mixer"], h),
                 "batch", "seq", "embed",

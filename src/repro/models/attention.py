@@ -100,8 +100,8 @@ def project_qkv(cfg: ModelConfig, st: Strategy, p: Params, xq, xkv, positions):
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
     if cfg.rope and positions is not None:
-        q = rope(q, positions, cfg.dh)
-        k = rope(k, positions, cfg.dh)
+        q = rope(q, positions, cfg.dh, cfg.rope_base, cfg.rotary_dims)
+        k = rope(k, positions, cfg.dh, cfg.rope_base, cfg.rotary_dims)
     B, S = q.shape[:2]
     T = k.shape[1]
     # q: (B,S,N=K*G,D) -> (B,S,K,G,D) -> pad G->Gp -> (B,S,KR,Gl,D)

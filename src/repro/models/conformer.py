@@ -40,11 +40,11 @@ def stage_forward(cfg: ModelConfig, st: Strategy, lp: Params, x):
     """One conformer layer; used as OneStageCompute in the pipeline wrapper."""
     B, S, M = x.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    h = rms_norm(x, lp["ln1"])
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     h = attn.self_attention(cfg, st, lp["attn"], h, positions, causal=False)
     x = x + h
-    h = rms_norm(x, lp["lnc"])
+    h = rms_norm(x, lp["lnc"], cfg.norm_eps)
     h = jax.nn.silu(_depthwise_conv(h, lp["conv_w"].astype(h.dtype)))
     x = x + h
-    h = rms_norm(x, lp["ln2"])
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     return x + mlp_forward(cfg, st, lp["mlp"], h)

@@ -54,14 +54,14 @@ def encode(cfg: ModelConfig, st: Strategy, params: Params, frames):
     x = st.constrain(frames.astype(jnp.dtype(cfg.dtype)), "batch", "seq", "embed")
 
     def layer_fn(lp, x, _):
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         h = attn.self_attention(cfg, st, lp["attn"], h, None, causal=False)
         x = st.constrain(x + h, "batch", "seq", "embed")
-        h = rms_norm(x, lp["ln2"])
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         return st.constrain(x + mlp_forward(cfg, st, lp["mlp"], h), "batch", "seq", "embed")
 
     x = stack_layers(layer_fn, params["enc_layers"], x, cfg)
-    return rms_norm(x, params["enc_ln"])
+    return rms_norm(x, params["enc_ln"], cfg.norm_eps)
 
 
 def decode_train(cfg: ModelConfig, st: Strategy, params: Params, tokens, enc_out):
@@ -70,18 +70,18 @@ def decode_train(cfg: ModelConfig, st: Strategy, params: Params, tokens, enc_out
     x = embed_lookup(cfg, st, params["embed"], tokens)
 
     def layer_fn(lp, x, _):
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         h = attn.self_attention(cfg, st, lp["attn"], h, positions, causal=True)
         x = st.constrain(x + h, "batch", "seq", "embed")
-        h = rms_norm(x, lp["lnx"])
+        h = rms_norm(x, lp["lnx"], cfg.norm_eps)
         ek, ev = attn.encode_kv(cfg, st, lp["xattn"], enc_out)
         h = attn.cross_attention(cfg, st, lp["xattn"], h, ek, ev)
         x = st.constrain(x + h, "batch", "seq", "embed")
-        h = rms_norm(x, lp["ln2"])
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         return st.constrain(x + mlp_forward(cfg, st, lp["mlp"], h), "batch", "seq", "embed")
 
     x = stack_layers(layer_fn, params["dec_layers"], x, cfg)
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     return unembed_logits(cfg, st, params["embed"], x)
 
 
@@ -108,13 +108,13 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
 
     def body(x, inp):
         lp, ck, cv, ek, ev = inp
-        h = rms_norm(x, lp["ln1"])
+        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         h, ck, cv = attn.decode_attention(cfg, st, lp["attn"], h, ck, cv, pos)
         x = x + h
-        h = rms_norm(x, lp["lnx"])
+        h = rms_norm(x, lp["lnx"], cfg.norm_eps)
         h = attn.cross_attention(cfg, st, lp["xattn"], h, ek, ev)
         x = x + h
-        h = rms_norm(x, lp["ln2"])
+        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         x = x + mlp_forward(cfg, st, lp["mlp"], h)
         return x, (ck, cv)
 
@@ -123,6 +123,6 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
         (params["dec_layers"], cache["k"], cache["v"], cache["ek"], cache["ev"]),
         cfg,
     )
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed_logits(cfg, st, params["embed"], x)
     return logits, {"k": ck, "v": cv, "ek": cache["ek"], "ev": cache["ev"]}

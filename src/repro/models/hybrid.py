@@ -52,13 +52,13 @@ def param_tree(cfg: ModelConfig, st: Strategy):
 def _sub_forward(cfg, st, idx, lp, x, positions):
     sb = superblock_size(cfg)
     is_attn = (idx % sb) == sb - 1
-    h = rms_norm(x, lp["ln1"])
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if is_attn:
         h = attn.self_attention(cfg, st, lp["mixer"], h, positions, causal=cfg.causal)
     else:
         h = ssm_forward(cfg, st, lp["mixer"], h)
     x = st.constrain(x + h, "batch", "seq", "embed")
-    h = rms_norm(x, lp["ln2"])
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if "router" in lp["ffn"]:
         y, aux = moe_forward(cfg, st, lp["ffn"], h)
@@ -89,7 +89,7 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
     (x, aux), _ = scan_or_loop(
         block_fn, (x, jnp.zeros((), jnp.float32)), params["blocks"], cfg
     )
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     return unembed_logits(cfg, st, params["embed"], x), aux
 
 
@@ -125,7 +125,7 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
         new_s, new_conv = [], []
         for i in range(sb):
             lp = bp[str(i)]
-            h = rms_norm(x, lp["ln1"])
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             if i == sb - 1:
                 h, ck, cv = attn.decode_attention(cfg, st, lp["mixer"], h, ck, cv, pos)
             else:
@@ -135,7 +135,7 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
                 new_s.append(st_new["s"])
                 new_conv.append(st_new["conv"])
             x = x + h
-            h = rms_norm(x, lp["ln2"])
+            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
             if "router" in lp["ffn"]:
                 y, _ = moe_forward(cfg, st, lp["ffn"], h)
             else:
@@ -150,6 +150,6 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
         (params["blocks"], cache["k"], cache["v"], cache["s"], cache["conv"]),
         cfg,
     )
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed_logits(cfg, st, params["embed"], x)
     return logits, {"k": ck, "v": cv, "s": s, "conv": conv}

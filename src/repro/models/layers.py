@@ -98,9 +98,13 @@ def rms_norm(x, scale, eps=1e-6):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(q, positions, dh, base=10000.0):
-    """Rotary embedding on the last dim; positions (B, S)."""
-    half = dh // 2
+def rope(q, positions, dh, base=10000.0, rotary_dims=None):
+    """Rotary embedding on the first ``rotary_dims`` (default all ``dh``) of
+    the last dim, half-split over those dims with the frequencies taken
+    over them; the other dims pass through (HF Phi-3's partial rotary).
+    positions (B, S)."""
+    rot = dh if rotary_dims is None else rotary_dims
+    half = rot // 2
     freqs = jnp.exp(
         -math.log(base) * jnp.arange(0, half, dtype=jnp.float32) / half
     )
@@ -108,10 +112,11 @@ def rope(q, positions, dh, base=10000.0):
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     while cos.ndim < q.ndim:
         cos, sin = cos[..., None, :], sin[..., None, :]
-    q1, q2 = q[..., :half], q[..., half:]
-    out = jnp.concatenate(
-        [q1 * cos - q2 * sin, q2 * cos + q1 * sin], axis=-1
-    )
+    q1, q2 = q[..., :half], q[..., half:rot]
+    parts = [q1 * cos - q2 * sin, q2 * cos + q1 * sin]
+    if rot < dh:
+        parts.append(q[..., rot:])
+    out = jnp.concatenate(parts, axis=-1)
     return out.astype(q.dtype)
 
 
@@ -226,6 +231,9 @@ def streamed_xent(cfg: ModelConfig, st: Strategy, x, embedding, labels):
 
     from .layers import scan_or_loop  # self-import ok at call time
 
+    # recompute each chunk's logits in the backward: saved, the scan's
+    # residuals would be the whole (B,S,V) logits the chunking avoids
+    body = jax.checkpoint(body, prevent_cse=False)
     xc = jnp.moveaxis(x.reshape(B, nc, Q, M), 1, 0)
     lc = jnp.moveaxis(labels.reshape(B, nc, Q), 1, 0)
     total, _ = scan_or_loop(body, jnp.zeros((), jnp.float32), (xc, lc), cfg)

@@ -31,11 +31,11 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
     x = embed_lookup(cfg, st, params["embed"], tokens)
 
     def layer_fn(lp, x, _):
-        h = rms_norm(x, lp["ln"])
+        h = rms_norm(x, lp["ln"], cfg.norm_eps)
         return st.constrain(x + ssm_forward(cfg, st, lp["mixer"], h), "batch", "seq", "embed")
 
     x = stack_layers(layer_fn, params["layers"], x, cfg)
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     return unembed_logits(cfg, st, params["embed"], x)
 
 
@@ -55,13 +55,13 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
 
     def body(x, inp):
         lp, s, conv = inp
-        h = rms_norm(x, lp["ln"])
+        h = rms_norm(x, lp["ln"], cfg.norm_eps)
         h, new = ssm_decode(cfg, st, lp["mixer"], h, {"s": s, "conv": conv})
         return x + h, (new["s"], new["conv"])
 
     x, (s, conv) = scan_or_loop(
         body, x, (params["layers"], cache["s"], cache["conv"]), cfg
     )
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed_logits(cfg, st, params["embed"], x)
     return logits, {"s": s, "conv": conv}

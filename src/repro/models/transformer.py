@@ -83,14 +83,14 @@ def decoder_layer(cfg: ModelConfig, st: Strategy, lp: Params, x, positions):
             h_src = st.constrain(x, "batch", "seq", None)
         else:
             h_src = x
-        h = rms_norm(h_src, lp["ln1"])
+        h = rms_norm(h_src, lp["ln1"], cfg.norm_eps)
         h = attn.self_attention(cfg, st, lp["attn"], h, positions,
                                 causal=cfg.causal)
     x = st.constrain(x + h, "batch", "seq", "embed")
     with jax.named_scope("mlp"):
         h_src = (st.constrain(x, "batch", "seq", None)
                  if cfg.gather_norm_input else x)
-        h = rms_norm(h_src, lp["ln2"])
+        h = rms_norm(h_src, lp["ln2"], cfg.norm_eps)
         aux = jnp.zeros((), jnp.float32)
         if "moe" in lp:
             y, aux = moe_forward(cfg, st, lp["moe"], h)
@@ -126,7 +126,7 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
         extra=positions,
     )
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_ln"])
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
         return unembed_logits(cfg, st, params["embed"], x), aux
 
 
@@ -154,7 +154,7 @@ def backbone(cfg: ModelConfig, st: Strategy, params: Params, tokens):
         extra=positions,
     )
     with jax.named_scope("head"):
-        return rms_norm(x, params["final_ln"]), aux
+        return rms_norm(x, params["final_ln"], cfg.norm_eps), aux
 
 
 def loss_fn(cfg: ModelConfig, st: Strategy, params: Params, batch, aux_coef=0.01):
@@ -181,10 +181,10 @@ def loss_fn(cfg: ModelConfig, st: Strategy, params: Params, batch, aux_coef=0.01
 def decode_layer(cfg: ModelConfig, st: Strategy, lp: Params, x, ck, cv, pos):
     from .moe import moe_forward
 
-    h = rms_norm(x, lp["ln1"])
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     h, ck, cv = attn.decode_attention(cfg, st, lp["attn"], h, ck, cv, pos)
     x = x + h
-    h = rms_norm(x, lp["ln2"])
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if "moe" in lp:
         y, _ = moe_forward(cfg, st, lp["moe"], h)
         if "mlp" in lp:
@@ -234,6 +234,6 @@ def decode_step(cfg: ModelConfig, st: Strategy, params: Params, token, cache, po
             cks.append(ck)
             cvs.append(cv)
         ck, cv = ckv(jnp.stack(cks)), ckv(jnp.stack(cvs))
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed_logits(cfg, st, params["embed"], x)
     return logits, {"k": ck, "v": cv}

@@ -39,7 +39,7 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens, patches):
         layer_fn, params["layers"], (x, jnp.zeros((), jnp.float32)), cfg,
         extra=positions,
     )
-    x = rms_norm(x, params["final_ln"])
+    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = unembed_logits(cfg, st, params["embed"], x[:, P:])
     return logits, aux
 

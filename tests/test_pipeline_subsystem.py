@@ -289,8 +289,10 @@ def test_solve_with_pipeline_returns_mixed_assignment():
     from repro import autoshard
 
     mesh = Mesh.create((2, 4), ("data", "model"))
+    # pure tensor parallelism peaks at 27.3 MB at best, the pipelined point
+    # at 23.0 MB (modeled; the embedding lookups run per shard)
     cfg = autoshard.AutoshardConfig(
-        budget_bytes=35e6, top_n=2, sa_steps=2, beam_width=2,
+        budget_bytes=25e6, top_n=2, sa_steps=2, beam_width=2,
         max_candidates=6,
     )
     kw = dict(batch=4, seq=32, reduce_k=6)
