@@ -172,6 +172,25 @@ def _nbytes_of(shape: Tuple[int, ...], dbytes: int) -> float:
     return b
 
 
+def kernel_calls(eqns) -> Dict[str, int]:
+    """Kernel name -> ``pallas_call`` equations among ``eqns`` and in the
+    jaxprs they hold (a ``custom_vjp_call``'s primal), each counted once.
+    A kernel's name is the one it was given, else its kernel function's
+    (``_flash_attention_kernel``)."""
+    out: Dict[str, int] = {}
+    for eqn in eqns:
+        if eqn.primitive.name == "pallas_call":
+            dbg = eqn.params["jaxpr"].debug_info
+            found = {eqn.params.get("name")
+                     or (dbg.func_name if dbg else "pallas_call"): 1}
+        else:
+            found = kernel_calls([e for sub in core.jaxprs_in_params(eqn.params)
+                                  for e in sub.eqns])
+        for k, n in found.items():
+            out[k] = out.get(k, 0) + n
+    return out
+
+
 def _read(env: Env, v):
     if isinstance(v, excore.Literal):
         return v.val
@@ -249,6 +268,9 @@ class PlanStats:
     # bytes one device receives per execution from the fallback's operand
     # gathers, as lowered (scan bodies at trip count)
     fallback_bytes: float = 0.0
+    # Pallas kernel name -> ``pallas_call`` equations run per execution
+    # (scan bodies at trip count)
+    kernel_calls: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def count(self, kind: str, n: int = 1) -> None:
         self.collectives[kind] = self.collectives.get(kind, 0) + n
@@ -286,6 +308,7 @@ class PlanStats:
             "fallbacks": dict(self.fallbacks),
             "sharded_gathers": self.sharded_gathers,
             "fallback_bytes": self.fallback_bytes,
+            "kernel_calls": dict(self.kernel_calls),
         }
 
 
@@ -1084,6 +1107,9 @@ class PlanBuilder:
         elif name in ("gather", "scatter-add") and index_dims(eqn) is not None:
             self._index_op(eqn, index_dims(eqn))
         else:
+            calls = self.stats.kernel_calls
+            for k, n in kernel_calls([eqn]).items():
+                calls[k] = calls.get(k, 0) + n * self.trips
             self._fallback(eqn)
 
     def _annotate(self, eqn) -> None:

@@ -73,6 +73,7 @@ ELEMENTWISE = {
     "acos", "atan", "sinh", "cosh", "asinh", "acosh", "atanh",
     "population_count", "clz", "reduce_precision", "gspmd_annotate",
     "optimization_barrier", "add_any",
+    "name",  # checkpoint_name: an identity tag for remat policies
 }
 
 

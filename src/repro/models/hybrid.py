@@ -17,7 +17,8 @@ from ..configs.base import ModelConfig, Strategy
 from . import attention as attn
 from .layers import (
     Params, embed_lookup, embed_params, mlp_forward, mlp_params, pspec,
-    rms_norm, scan_or_loop, softmax_xent, stacked, unembed_logits,
+    remat_policy, rms_norm, scan_or_loop, softmax_xent, stacked,
+    unembed_logits,
 )
 from .moe import moe_forward, moe_params
 from .ssm import ssm_decode, ssm_forward, ssm_params, ssm_state_shapes
@@ -82,10 +83,7 @@ def forward(cfg: ModelConfig, st: Strategy, params: Params, tokens):
 
     if cfg.remat != "none":
         block_fn = jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            prevent_cse=False,
-        )
+            block_fn, policy=remat_policy("dots"), prevent_cse=False)
     (x, aux), _ = scan_or_loop(
         block_fn, (x, jnp.zeros((), jnp.float32)), params["blocks"], cfg
     )

@@ -17,6 +17,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig, Strategy
 from ..core.sharding import pad_to_multiple
+from ..kernels import flash_attention
 
 Params = Dict[str, Any]
 
@@ -245,6 +246,23 @@ def streamed_xent(cfg: ModelConfig, st: Strategy, x, embedding, labels):
 # ---------------------------------------------------------------------------------
 
 
+# one object, so that every trace names the same policy
+_DOTS_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+    jax.checkpoint_policies.save_only_these_names(
+        *flash_attention.RESIDUAL_NAMES))
+
+
+def remat_policy(remat: str):
+    """The ``jax.checkpoint`` policy of ``ModelConfig.remat``: "full" saves
+    nothing (None), "dots" saves the matmuls without batch dims and the
+    flash kernel's residuals, so the backward runs only its backward
+    kernels.  The names exist only where ``self_attention`` took the
+    kernel; elsewhere "dots" decides every equation as the matmul policy
+    alone would."""
+    return None if remat == "full" else _DOTS_POLICY
+
+
 def stack_layers(layer_fn, params_stacked, x, cfg: ModelConfig, extra=None):
     """Run a stack of identical layers: scan when cfg.scan_layers (small HLO;
     production) else a Python loop (used with scan_unroll for exact roofline
@@ -254,14 +272,9 @@ def stack_layers(layer_fn, params_stacked, x, cfg: ModelConfig, extra=None):
         out = layer_fn(lp, carry, extra)
         return out, None
 
-    if cfg.remat == "full":
-        body = jax.checkpoint(body, prevent_cse=False)
-    elif cfg.remat == "dots":
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            prevent_cse=False,
-        )
+    if cfg.remat in ("full", "dots"):
+        body = jax.checkpoint(body, policy=remat_policy(cfg.remat),
+                              prevent_cse=False)
     if cfg.scan_layers:
         x, _ = jax.lax.scan(body, x, params_stacked, unroll=cfg.scan_unroll)
         return x

@@ -4,24 +4,30 @@ bypass the flash kernel stay as they were.
 The kernel path needs a TPU backend; the tests patch
 ``repro.kernels.ops.on_tpu``, and on the CPU the kernels run in the Pallas
 TPU interpreter."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.base import get_strategy
 from repro.configs.registry import get_config
 from repro.core.compat import make_jax_mesh, trace_for
 from repro.core.partitioner import spmd_partition
+from repro.core.plan import kernel_calls
 from repro.core.sharding import Mesh
 from repro.kernels import ops
 from repro.launch.train import reduced_config
 from repro.models import attention as attn
+from repro.models import layers
 from repro.models.layers import tree_init
 from repro.obs import metrics
 
 ST = get_strategy("2d_finalized")
 PATHS = ("attention.flash_kernel", "attention.xla_chunked")
+FWD = "_flash_attention_kernel"  # the shipped forward kernel
 
 
 @pytest.fixture
@@ -89,19 +95,104 @@ def _plan_jaxpr(runner, leaves):
 def test_train_step_traces_the_kernel_under_the_attention_scope(
         tpu, monkeypatch):
     """A reduced-Qwen train step through ``spmd_partition`` on a 1x1 mesh,
-    traced as for the chip: the kernel's forward, rematerialised forward
-    and backward all sit under ``attention``, the backward under
+    traced as for the chip: the kernel's forward and its dK/dV and dQ
+    kernels all sit under ``attention``, the backward under
+    ``transpose(...)``.  Layer remat keeps the forward's residuals, so the
+    plan runs each kernel once per layer and the forward never under
     ``transpose(...)``."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = _cfg(attn_chunk=128)
     before = _counts()
-    names = _pallas_names(_plan_jaxpr(*_partitioned(
-        *_train_step(_cfg(attn_chunk=128)))))
+    runner, leaves = _partitioned(*_train_step(cfg))
+    names = _pallas_names(_plan_jaxpr(runner, leaves))
     took = _took(before)
     assert took["attention.flash_kernel"] > 0
     assert took["attention.xla_chunked"] == 0
-    assert len(names) >= 4 and all("attention" in n for n in names), names
-    assert any("transpose(" in n for n in names)
-    assert any("transpose(" not in n for n in names)
+    assert len(names) == 3 and all("attention" in n for n in names), names
+    assert sum("transpose(" in n for n in names) == 2
+    (entry,) = runner.plans.values()
+    assert entry.plan.stats.kernel_calls == {
+        k: cfg.num_layers for k in (FWD, "_flash_attention_dkv_kernel",
+                                    "_flash_attention_dq_kernel")}
+
+
+def _matmul_policy(remat):
+    return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scan", "loop"])
+def test_layer_remat_saves_the_forward_kernel_residuals(
+        tpu, monkeypatch, scan_layers):
+    """Two layers under ``remat="dots"`` on the kernel path: with
+    ``remat_policy`` the gradient's program holds one forward kernel per
+    layer body (the backward reads the saved o, l and m), with the matmul
+    policy alone two; loss and gradients are the same to the bit.  The
+    kernels run in Pallas's HLO interpreter: the TPU interpreter's
+    callbacks cannot be rematerialised."""
+    from repro.models.api import loss_fn, param_tree
+
+    cfg = _cfg(attn_chunk=128, scan_layers=scan_layers)
+    assert cfg.remat == "dots" and cfg.num_layers == 2
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    params = tree_init(param_tree(cfg, ST), jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+
+    def run():
+        jax.clear_caches()
+        vg = jax.value_and_grad(lambda p: loss_fn(cfg, ST, p, batch))
+        with pltpu.force_tpu_interpret_mode(True):
+            calls = kernel_calls(jax.make_jaxpr(vg)(params).eqns)
+            out = jax.block_until_ready(jax.jit(vg)(params))
+        return calls, jax.tree_util.tree_leaves(out)
+
+    bodies = 1 if scan_layers else cfg.num_layers
+    bwd = {"_flash_attention_dkv_kernel": bodies,
+           "_flash_attention_dq_kernel": bodies}
+    calls, got = run()
+    assert calls == {FWD: bodies, **bwd}
+    monkeypatch.setattr(layers, "remat_policy", _matmul_policy)
+    calls, want = run()
+    assert calls == {FWD: 2 * bodies, **bwd}
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_layer_remat_leaves_the_chunked_loop_as_it_was(tpu):
+    """A Phi-shaped step (24 q over 8 kv heads, cut to 6 over 2: G = 3)
+    traced for a 2x2 mesh takes the chunked loop, which saves no kernel
+    residuals: its differentiated program under ``remat_policy("dots")`` is
+    the one the matmul policy alone gives, as a string, once the policy
+    the remat equations name is left out."""
+    cfg = reduced_config(get_config("phi4-mini-3.8b"), 8).with_(xent_chunk=0)
+    assert (cfg.num_heads, cfg.num_kv_heads) == (6, 2)
+
+    def program():
+        from repro.train.loop import TrainConfig, init_state, make_train_step
+        from repro.train.optimizer import get_optimizer
+
+        jax.clear_caches()
+        opt, tc = get_optimizer("adafactor", lr=0.01), TrainConfig()
+        state = jax.eval_shape(
+            lambda: init_state(cfg, ST, opt, tc, jax.random.PRNGKey(0)))
+        batch = {k: jax.ShapeDtypeStruct((4, 256), jnp.int32)
+                 for k in ("tokens", "labels")}
+        before = _counts()
+        text = str(trace_for(Mesh.create((2, 2), ("data", "model")),
+                             make_train_step(cfg, ST, opt, tc), state, batch))
+        assert _took(before)["attention.flash_kernel"] == 0
+        return text
+
+    new = program()
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(layers, "remat_policy", _matmul_policy)
+        old = program()
+    assert "save_from_both_policies" in new and "pallas_call" not in new
+    assert new != old
+    policy = re.compile(r"policy=<function [^\n]*")
+    assert policy.sub("policy", new) == policy.sub("policy", old)
 
 
 def test_train_step_through_the_kernel_matches_the_chunked_loop(tpu):
