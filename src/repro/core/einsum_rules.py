@@ -19,8 +19,7 @@ analysis layer to predict GSPMD's collectives); ``compile_einsum`` extends its
 output with cost-model-chosen reshard programs and the ReduceScatter-vs-
 AllReduce decision (an executable plan, computed once per cached partition
 plan); ``execute_einsum`` replays a compiled plan on local shards inside a
-shard_map region; ``partitioned_einsum`` is compile+execute in one call for
-the dynamic reference path.
+shard_map region; ``partitioned_einsum`` is compile+execute in one call.
 """
 from __future__ import annotations
 
@@ -327,8 +326,8 @@ def partitioned_einsum(
     Returns (local_result, result_sharding).  If ``out_sh`` is given, the result
     is resharded to it; a pending partial sum combined with a requested sharding
     on a psum axis becomes a ReduceScatter (§4.2: "half the cost of AllReduce").
-    Compile+execute in one call — the compiled-plan path caches the
-    ``compile_einsum`` half across calls.
+    Compile+execute in one call; the partitioner (``core/plan.py``) calls
+    ``compile_einsum`` once at plan time and replays it on every execution.
     """
     plan = compile_einsum(
         spec, lhs_sh, rhs_sh, out_sh, tuple(x.shape), tuple(y.shape),

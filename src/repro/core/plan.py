@@ -1,11 +1,26 @@
-"""Compiled partition plans: plan-once / execute-many for the reference
-partitioner (paper §4, PartIR-style decision/execution split).
+"""Compiled partition plans: the SPMD partitioner's per-op rules (paper §4)
+and the plan they lower into (a PartIR-style decision/execution split).
 
-The dynamic reference path (``SpmdPartitioner``) re-dispatches every equation
-through Python on every trace: read shardings, classify the op, decide the
-reshard, emit collectives.  All of those decisions depend only on the jaxpr,
-the mesh, and the propagated shardings — never on data — so they can be made
-exactly once.  This module lowers a propagated jaxpr into a
+XLA's SPMD partitioner (production GSPMD) is what ``jax.jit`` invokes; this
+module is the repo's own implementation of the same transformation, run as a
+single program over local shards inside one ``shard_map`` region, with
+explicit ``jax.lax`` collectives.  Per op:
+
+* dot_general  — einsum partitioning with recursive grouping (§4.4)
+                 (``einsum_rules.compile_einsum``: AllReduce / ReduceScatter
+                 / AllGather as required);
+* elementwise  — operands resharded to the merged sharding, computed locally;
+* reduce       — local reduce + psum over mesh axes sharding reduced dims;
+* conv         — halo exchange on sharded spatial dims (§4.3);
+* gather / scatter-add — run per shard, with a psum over the indexed dims'
+                 axes (``_index_op``);
+* annotate     — explicit resharding to the user's annotation;
+* anything else — the fallback: AllGather the operands, run the op, reshard
+                 the result (counted in ``PlanStats.fallbacks``).
+
+Which rule applies, which reshard to emit and psum-vs-scatter depend only on
+the jaxpr, the mesh and the propagated shardings — never on data — so they
+are decided exactly once.  This module lowers a propagated jaxpr into a
 :class:`PartitionPlan`: a flat list of per-equation *steps*, each a
 :class:`PlanStep` over pre-resolved decisions —
 
@@ -864,10 +879,9 @@ CALL_SCOPES = {"closed_call": "closed_call", "remat2": "checkpoint"}
 class PlanBuilder:
     """Walks a propagated jaxpr once and emits resolved execution steps.
 
-    Mirrors ``SpmdPartitioner``'s per-op semantics, but every decision that
-    the dynamic path makes while tracing (merge targets, reshard sequences,
-    psum-vs-scatter, fallback gathers) is made here, at plan time, from
-    shardings and static shapes alone.
+    Every decision (merge targets, reshard sequences, psum-vs-scatter,
+    fallback gathers) is made here, at plan time, from shardings and static
+    shapes alone.
 
     Reshards of operands and trailing partial-sum collectives are emitted as
     *separate* steps (not folded into compute closures) so the optimizer
@@ -1911,7 +1925,7 @@ def compile_plan(
     mesh: Mesh,
     optimize: bool = True,
     cost_only: bool = False,
-    verify: Optional[bool] = None,
+    verify: bool = True,
     guard: Optional[GuardConfig] = None,
     profile: Optional[object] = None,
     phases: Optional[Dict[str, float]] = None,
@@ -1930,10 +1944,8 @@ def compile_plan(
     ``guard`` appends the numerics-sentinel epilogue
     (:func:`append_guard_steps`) *before* optimization, so the guard
     collective is fused/scheduled like any other.  ``verify`` runs the static
-    plan verifier (``plan_verify.verify_plan``) on the finished plan;
-    ``None`` means the module default (on unless ``REPRO_PLAN_VERIFY=0``) —
-    cheap enough to leave on everywhere, including cost-only autoshard
-    lowerings.
+    plan verifier (``plan_verify.verify_plan``) on the finished plan — cheap
+    enough to leave on everywhere, including cost-only autoshard lowerings.
 
     ``profile`` attaches a calibrated
     :class:`repro.analysis.roofline.RooflineParams` to the plan *before*
@@ -1970,9 +1982,7 @@ def compile_plan(
         plan.peak_bytes = plan_peak_bytes(plan)
     t1 = thread_search_telemetry()
     plan.stats.lattice = {k: t1[k] - t0[k] for k in t1}
-    from .plan_verify import verify_enabled
-
-    if verify_enabled(verify):
+    if verify:
         from .plan_verify import verify_plan
 
         with span("repro.partition.verify", phases):
@@ -2154,7 +2164,7 @@ def lower_for_cost(
     in_shardings,
     mesh: Mesh,
     optimize: bool = True,
-    verify: Optional[bool] = None,
+    verify: bool = True,
     guard: Optional[GuardConfig] = None,
     profile: Optional[object] = None,
 ) -> PlanCost:
@@ -2167,7 +2177,7 @@ def lower_for_cost(
     :class:`~repro.core.collective_planner.PlanError` when the propagated
     program demands a reshard the planner cannot express (infeasible
     candidate — autoshard treats it as infinite cost).  Cost-only lowerings
-    are verified too (``verify=None`` = module default); ``guard`` prices the
+    are verified too (unless ``verify=False``); ``guard`` prices the
     numerics-sentinel epilogue into the returned cost (the guard-overhead
     bench cell); ``profile`` prices with calibrated roofline constants
     (:class:`repro.analysis.roofline.RooflineParams`).
@@ -2181,7 +2191,7 @@ def lower_plan(
     in_shardings,
     mesh: Mesh,
     optimize: bool = True,
-    verify: Optional[bool] = None,
+    verify: bool = True,
     guard: Optional[GuardConfig] = None,
     profile: Optional[object] = None,
 ) -> PartitionPlan:
@@ -2335,7 +2345,7 @@ class StateReshardPlan:
 
 
 def compile_state_reshard(items, mesh: Mesh,
-                          verify: Optional[bool] = None) -> StateReshardPlan:
+                          verify: bool = True) -> StateReshardPlan:
     """Lower a cross-topology state restore into a :class:`StateReshardPlan`.
 
     ``items`` is an iterable of ``(key, src, dst, global_shape, dtype)`` with
@@ -2368,9 +2378,7 @@ def compile_state_reshard(items, mesh: Mesh,
                 pass
         leaves.append(LeafReshard(key, src, dst, shape, str(dtype), prog))
     plan = StateReshardPlan(mesh, leaves, stats, gather_bytes)
-    from .plan_verify import verify_enabled
-
-    if verify_enabled(verify):
+    if verify:
         from .plan_verify import verify_state_reshard
 
         verify_state_reshard(plan)

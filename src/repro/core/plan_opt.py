@@ -86,9 +86,9 @@ Pass-ordering invariants
 Verifier contract (``core/plan_verify.py``)
 -------------------------------------------
 Every plan leaving ``compile_plan`` is re-checked by the static plan
-verifier (on by default; ``REPRO_PLAN_VERIFY=0`` or ``verify=False``
-disables).  A new pass therefore does not get to *assume* it preserved the
-invariants above — the verifier re-derives them from the final step list and
+verifier (on by default; ``verify=False`` disables).  A new pass
+therefore does not get to *assume* it preserved the invariants above — the
+verifier re-derives them from the final step list and
 raises :class:`~repro.core.plan_verify.PlanVerifyError` on the first plan
 that breaks one:
 

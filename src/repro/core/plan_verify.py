@@ -7,7 +7,7 @@ this module, those promises could only be falsified by wrong numerics
 surfacing in the multidev suite.  :func:`verify_plan` checks them directly, in
 one linear walk over the step list, cheap enough to leave on for every
 compile (it is the default in ``compile_plan`` / ``spmd_partition`` /
-``lower_for_cost``, switchable with ``REPRO_PLAN_VERIFY=0``):
+``lower_for_cost``; ``verify=False`` skips it):
 
 **Dataflow well-formedness**
   * every ``reads`` key is produced before use (plan inputs/consts, or an
@@ -67,16 +67,12 @@ asserts each is caught.
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 from jax import core
 from jax.extend import core as excore
 
 from .collective_planner import PlanError, simulate
-
-# module switch: default on; REPRO_PLAN_VERIFY=0 disables everywhere
-VERIFY_DEFAULT = os.environ.get("REPRO_PLAN_VERIFY", "1") != "0"
 
 # telemetry consumed by benchmarks/plan_smoke.py → BENCH_plan.json: how many
 # top-level plans this process verified and how many violations were found
@@ -84,11 +80,6 @@ VERIFY_DEFAULT = os.environ.get("REPRO_PLAN_VERIFY", "1") != "0"
 _TELEMETRY = {"plans_verified": 0, "violations": 0}
 
 _REL_TOL = 1e-3  # byte-accounting tolerance (float accumulation order)
-
-
-def verify_enabled(flag: Optional[bool]) -> bool:
-    """Resolve a tri-state ``verify=`` argument against the module default."""
-    return VERIFY_DEFAULT if flag is None else bool(flag)
 
 
 def verify_telemetry() -> Dict[str, int]:

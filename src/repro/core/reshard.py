@@ -20,10 +20,10 @@ bounded branch-and-bound over step interleavings (lattice search) with the
 greedy result as the incumbent, finding e.g. AllToAll detours that park an
 axis on another dim so slices can shrink it before it returns.
 
-``reshard_local(x, cur, tgt)`` is the plan-then-execute convenience used by
-the dynamic reference partitioner; the compiled-plan path
-(``core/plan.py``) calls ``plan_reshard`` once at plan time, emits the result
-as a first-class reshard step, and replays the program on every execution —
+``reshard_local(x, cur, tgt)`` plans and executes in one call, for code that
+reshards a local value directly.  The partitioner (``core/plan.py``) calls
+``plan_reshard`` once at plan time, emits the result as a first-class
+reshard step, and replays the program on every execution —
 whether the step executes where the builder put it is then the whole-program
 optimizer's business (``core/plan_opt.py``: CSE across call boundaries once
 jit bodies are inlined, hoisting out of scan bodies, fusion, overlap

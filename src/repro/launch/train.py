@@ -4,8 +4,8 @@
         --reduce 8 --steps 100 --ckpt-dir /tmp/ckpt
 
 ``--reduce k`` divides layers/width/vocab by ~k for CPU-runnable examples;
-``--reduce 1`` trains the published config (``chip_smoke.py`` runs
-qwen1.5-0.5b that way on one TPU chip).  JAX's persistent compilation cache
+``--reduce 1`` trains the published config (qwen1.5-0.5b trains that way on
+one TPU chip).  JAX's persistent compilation cache
 is on for every run (:func:`enable_compile_cache`).
 """
 from __future__ import annotations

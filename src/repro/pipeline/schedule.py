@@ -144,15 +144,15 @@ class ScheduleCost:
 
 def schedule_cost(closed, assignment, mesh, decision: PipelineDecision,
                   state_shape=None, dtype_bytes: int = 4,
-                  verify=None) -> ScheduleCost:
+                  verify: bool = True) -> ScheduleCost:
     """Price one pipelined (jaxpr, assignment) pair: cost-only lower it and
     read the ppermute traffic off the plan, plus the analytic terms.
 
     ``state_shape`` (global shifting-buffer shape, leading stage dim) sizes
     the per-device microbatch activation; when omitted it is inferred as 0.
-    The cost-only lowering runs the static plan verifier (``verify=None`` =
-    module default) — pipelined plans get the same well-formedness guarantees
-    as executable ones.
+    The cost-only lowering runs the static plan verifier (unless
+    ``verify=False``) — pipelined plans get the same well-formedness
+    guarantees as executable ones.
     """
     from repro.core.plan import compile_plan, plan_cost
     from repro.core.propagation import propagate

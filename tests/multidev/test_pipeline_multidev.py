@@ -74,7 +74,7 @@ def test_pipelined_loss_and_grads_match_unpipelined_reference():
 
 def test_pipelined_plan_issues_one_ppermute_per_tick():
     wstk = np.asarray(stage_stack_params(jnp.asarray(WS), 4))
-    r = spmd_partition(pipelined_loss, jmesh, mesh, process_cache=False)
+    r = spmd_partition(pipelined_loss, jmesh, mesh)
     loss = r(wstk, XS)
     assert np.isfinite(np.asarray(loss))
     (entry,) = r.plans.values()

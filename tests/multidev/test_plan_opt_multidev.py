@@ -23,9 +23,9 @@ rng = np.random.default_rng(7)
 
 
 def _runner(f, optimize):
-    # process_cache=False: these tests compare plan *structure* across
-    # optimize settings and must not alias entries
-    return spmd_partition(f, jmesh, mesh, optimize=optimize, process_cache=False)
+    # these tests compare plan *structure* across optimize settings;
+    # ``optimize`` is part of the process-cache key, so the two never alias
+    return spmd_partition(f, jmesh, mesh, optimize=optimize)
 
 
 def _the_plan(runner):

@@ -100,14 +100,6 @@ def test_spmd_partition_guard_raises():
     assert any(f["kind"] == "nonfinite" for f in ei.value.faults)
 
 
-def test_guard_requires_compiled_plans():
-    mesh = Mesh.create((1,), ("x",))
-    jmesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
-    with pytest.raises(ValueError):
-        spmd_partition(lambda a: a, jmesh, mesh, compile_plans=False,
-                       guard=GuardConfig())
-
-
 # ---------------------------------------------------------------------------------
 # train-step skip semantics + escalation
 # ---------------------------------------------------------------------------------

@@ -372,16 +372,6 @@ def test_disabled_trace_config_is_normalized_away():
     assert off.tracer is None and base.tracer is None
 
 
-def test_trace_requires_compiled_plans():
-    from repro.core.partitioner import spmd_partition
-
-    m1 = Mesh.create((1,), ("x",))
-    jmesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
-    with pytest.raises(ValueError):
-        spmd_partition(lambda a: a, jmesh, m1, compile_plans=False,
-                       trace=obs.TraceConfig())
-
-
 def test_trace_write_roundtrip(tmp_path):
     plan = _plan(_mlp, *MLP_AVALS)
     tr = trace.Tracer(trace.TraceConfig(measured=False))

@@ -561,7 +561,9 @@ def test_lattice_never_worse_than_pr1_planner(d0, d1, e0, e1):
 # ---------------------------------------------------------------------------------
 
 
-def test_process_cache_shared_across_runners():
+@pytest.mark.parametrize("verify2", [{}, {"verify": True}],
+                         ids=["default", "verify_true"])
+def test_process_cache_shared_across_runners(verify2):
     from repro.core.compat import make_jax_mesh
     from repro.core.partitioner import (
         clear_process_plan_cache, process_plan_cache_stats, spmd_partition,
@@ -584,7 +586,8 @@ def test_process_cache_shared_across_runners():
     r1 = spmd_partition(make_fn(), jmesh, m)
     out1 = r1(x, x)
     assert process_plan_cache_stats().as_dict()["misses"] == 1
-    r2 = spmd_partition(make_fn(), jmesh, m)
+    # passing the default explicitly must not key a second entry
+    r2 = spmd_partition(make_fn(), jmesh, m, **verify2)
     out2 = r2(x, x)
     st = process_plan_cache_stats().as_dict()
     assert st["hits"] == 1 and st["misses"] == 1

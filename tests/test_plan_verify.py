@@ -32,7 +32,7 @@ def _f32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.float32)
 
 
-def _plan(f, *avals, optimize=True, verify=None):
+def _plan(f, *avals, optimize=True, verify=True):
     closed = jax.make_jaxpr(f)(*avals)
     prop = propagate(closed, mesh).result()
     return compile_plan(closed, prop, mesh, optimize=optimize, verify=verify)

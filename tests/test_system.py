@@ -34,7 +34,7 @@ def test_compile_cache_dir_is_placed_from_outside(monkeypatch):
         assert enable_compile_cache() == str(DEFAULT_COMPILE_CACHE)
         assert jax.config.jax_compilation_cache_dir == str(DEFAULT_COMPILE_CACHE)
         # a fixed directory at the root of the checkout
-        assert (DEFAULT_COMPILE_CACHE.parent / "chip_smoke.py").exists()
+        assert (DEFAULT_COMPILE_CACHE.parent / "BENCHMARK.json").exists()
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 
